@@ -26,9 +26,9 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.parallel.collectives import (ring_all_reduce,
     binary_exchange_all_to_all, all_to_all_baseline)
+from repro.runtime import make_mesh
 
-mesh = jax.make_mesh((8,), ("model",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+mesh = make_mesh((8,), ("model",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 1024, 256))
 sm = lambda f: jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("model"),
                                      out_specs=P("model")))
@@ -61,6 +61,9 @@ def run():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
     env.pop("XLA_FLAGS", None)
+    # the child measures 8 forced host devices, and an accelerator belongs
+    # to one process: the parent (``benchmarks.run``) may hold it already
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run([sys.executable, "-c", _CHILD], capture_output=True,
                          text=True, env=env, timeout=600)
     if res.returncode == 0:

@@ -18,9 +18,13 @@ import json
 import os
 import sys
 import time
+from pathlib import Path
 from typing import Callable, Optional
 
 from repro import obs
+from repro.runtime import enable_compile_cache
+
+ROOT = Path(__file__).resolve().parents[1]
 
 #: Known tcmalloc locations (the fleet-standard ``LD_PRELOAD`` for JAX CPU
 #: hosts; see the CI workflow, which preloads it when the distro ships it).
@@ -40,9 +44,12 @@ def pin_runtime(devices: Optional[int] = None) -> dict:
     ``REPRO_BENCH_DEVICES`` environment variable -- and no count is pinned
     already.  ``LD_PRELOAD`` (tcmalloc) cannot be applied from inside a
     running process, so it is *reported*, not set: the CI workflow exports
-    it when the library exists.  The returned dict is embedded in every
-    gated payload (see :func:`write_json`) so a baseline records the
-    runtime it was measured under.
+    it when the library exists.  JAX's persistent compilation cache is
+    turned on (``repro.runtime.enable_compile_cache``: the
+    ``JAX_COMPILATION_CACHE_DIR`` directory, else ``<repo>/.jax_cache``).
+    The returned dict is embedded in every gated payload (see
+    :func:`write_json`) so a baseline records the runtime it was measured
+    under.
     """
     if devices is None:
         env = os.environ.get("REPRO_BENCH_DEVICES", "").strip()
@@ -56,6 +63,10 @@ def pin_runtime(devices: Optional[int] = None) -> dict:
     # REPRO_TRACE exported trace); enabled-path overhead is block-granular
     # and the scale section's throughput gates bound it
     obs.enable()
+    # a pin after jax backend init is a no-op; record it so a baseline
+    # measured that way is visibly suspect (read before the cache import)
+    preinitialized = "jax" in sys.modules
+    cache_dir = enable_compile_cache(ROOT)
     preload = os.environ.get("LD_PRELOAD", "")
     runtime = {
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
@@ -63,9 +74,8 @@ def pin_runtime(devices: Optional[int] = None) -> dict:
         "tcmalloc_available": next(
             (p for p in TCMALLOC_PATHS if os.path.exists(p)), None),
         "cpu_count": os.cpu_count(),
-        # a pin after jax backend init is a no-op; record it so a baseline
-        # measured that way is visibly suspect
-        "jax_preinitialized": "jax" in sys.modules,
+        "compile_cache": cache_dir,
+        "jax_preinitialized": preinitialized,
     }
     _RUNTIME.clear()
     _RUNTIME.update(runtime)

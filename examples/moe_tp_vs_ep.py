@@ -41,6 +41,9 @@ def compiled():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(ROOT / "src")
     env.pop("XLA_FLAGS", None)
+    # the check runs on 8 forced host devices; keep the child off any
+    # accelerator, which belongs to one process at a time
+    env["JAX_PLATFORMS"] = "cpu"
     res = subprocess.run(
         [sys.executable, str(ROOT / "tests" / "_sharded_checks.py"), "moe"],
         capture_output=True, text=True, env=env, timeout=900)

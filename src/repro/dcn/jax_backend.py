@@ -5,8 +5,8 @@ pure ``jax.numpy`` function of ONE snapshot mask -- masked tier carves,
 count-vector binary search (``fori_loop`` with a static trip count),
 scatter/lexsort materialization -- composed under ``jax.vmap`` over the
 snapshot axis and ``jax.jit`` over the grid, with the snapshot axis
-sharded across devices via ``shard_map`` (same layout as
-``repro.sim.jax_backend``).
+sharded across the engine's devices via ``jax.shard_map`` (same layout
+and device choice as ``repro.sim.jax_backend``).
 
 The device kernel emits the placement *member* grid; DP-ring pair counting
 happens on the host through the identical ``kernel.batched_pair_counts``
@@ -28,14 +28,13 @@ try:  # keep repro.dcn importable on numpy-only installs
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from ..parallel.compat import make_mesh, shard_map
     HAVE_JAX = True
     _IMPORT_ERROR: Optional[BaseException] = None
 except Exception as e:  # pragma: no cover - exercised on jax-free installs
     HAVE_JAX = False
     _IMPORT_ERROR = e
 
+from ..runtime import engine_devices, snapshot_mesh
 from .kernel import BatchedPlacement, FatTreeConfig
 
 _SNAP_AXIS = "snap"
@@ -48,7 +47,7 @@ def require() -> None:
 
 
 def num_devices() -> int:
-    return len(jax.devices()) if HAVE_JAX else 0
+    return len(engine_devices()) if HAVE_JAX else 0
 
 
 # ---------------------------------------------------------------- kernel
@@ -191,24 +190,19 @@ def _snapshot_fn(cfg: FatTreeConfig, tp_sizes: Sequence[int],
 _GRID_CACHE: Dict[Tuple, Callable] = {}
 
 
-def _mesh():
-    devs = jax.devices()
-    if len(devs) > 1:
-        return make_mesh((len(devs),), (_SNAP_AXIS,))
-    return None
-
-
 def _grid_fn(cfg: FatTreeConfig, tp_sizes: Tuple[int, ...],
              job_gpus: Tuple[int, ...], mesh) -> Callable:
-    key = (cfg, tp_sizes, job_gpus,
-           None if mesh is None else mesh.devices.size)
+    key = (cfg, tp_sizes, job_gpus, mesh)
     fn = _GRID_CACHE.get(key)
     if fn is not None:
         return fn
     batched = jax.vmap(_snapshot_fn(cfg, tp_sizes, job_gpus))
     if mesh is not None:
-        batched = shard_map(batched, mesh=mesh,
-                            in_specs=P(_SNAP_AXIS), out_specs=P(_SNAP_AXIS))
+        # check_vma off: each shard's body is local (no collectives), and
+        # the binary search's fori_loop starts from unvarying constants
+        # that the varying-axes checker would otherwise reject
+        batched = jax.shard_map(batched, mesh=mesh, in_specs=P(_SNAP_AXIS),
+                                out_specs=P(_SNAP_AXIS), check_vma=False)
     fn = jax.jit(batched, donate_argnums=0)
     _GRID_CACHE[key] = fn
     return fn
@@ -240,7 +234,7 @@ def fat_tree_placements(masks: np.ndarray, cfg: FatTreeConfig,
     if snaps == 0:
         return outs
 
-    mesh = _mesh()
+    mesh = snapshot_mesh(_SNAP_AXIS)
     ndev = 1 if mesh is None else mesh.devices.size
     chunk = max(1, chunk_snapshots)
     chunk = -(-chunk // ndev) * ndev

@@ -13,13 +13,13 @@ from typing import Optional, Set
 
 import jax
 
-from ..parallel.compat import make_auto_mesh
+from ..runtime import make_mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_auto_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_orchestrated_production_mesh(*, multi_pod: bool = False,

@@ -34,7 +34,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
-from repro.parallel.compat import shard_map
 from repro.models import layers as L
 from repro.models import moe as MOE
 from repro.models import rglru as RG
@@ -175,7 +174,7 @@ def embed_tokens(params: Dict, cfg: ModelConfig, ids: jnp.ndarray) -> jnp.ndarra
         out = jnp.where(ok[..., None], out, jnp.zeros_like(out))
         return lax.psum(out, axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis, None), P(batch, None)),
         out_specs=P(batch, None, None))(emb, ids)
@@ -222,7 +221,7 @@ def lm_loss(params: Dict, cfg: ModelConfig, x: jnp.ndarray,
         loss = (mx + jnp.log(se)) - lab                    # (b,s)
         return loss
 
-    per_tok = shard_map(
+    per_tok = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(batch, None, None), P(None, axis), P(batch, None)),
         out_specs=P(batch, None))(x, w, labels)
@@ -337,7 +336,7 @@ def _moe_dispatch(p: Dict, cfg: ModelConfig, x: jnp.ndarray,
     # check_vma off: replication of the output over the model axis comes
     # from the explicit ring all-reduce / all-to-all pair, which the static
     # replication checker cannot see through (ppermute chains).
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(wspec, P(batch, None, None)),
         out_specs=P(batch, None, None), check_vma=False)(p, x)

@@ -27,12 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import compat
-
-
-def _axis_size(axis_name: str) -> int:
-    return compat.axis_size(axis_name)
-
 
 def ring_reduce_scatter(x: jnp.ndarray, axis_name: str,
                         scatter_axis: int = 0) -> jnp.ndarray:
@@ -42,7 +36,7 @@ def ring_reduce_scatter(x: jnp.ndarray, axis_name: str,
     reduced chunk i (along ``scatter_axis``).  Every step sends one chunk to
     the +1 neighbor -- on the orchestrated mesh this is a live OCSTrx link.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -68,7 +62,7 @@ def ring_reduce_scatter(x: jnp.ndarray, axis_name: str,
 def ring_all_gather(x: jnp.ndarray, axis_name: str,
                     gather_axis: int = 0) -> jnp.ndarray:
     """Ring all-gather via n-1 neighbor ppermutes (chunks rotate around)."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     idx = lax.axis_index(axis_name)
@@ -94,7 +88,7 @@ def ring_all_reduce(x: jnp.ndarray, axis_name: str, impl: str = "ring",
     (paper-faithful HBD traffic), ``impl='psum'`` the XLA primitive."""
     if impl == "psum":
         return lax.psum(x, axis_name)
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     axis = chunk_axis
@@ -125,7 +119,7 @@ def binary_exchange_all_to_all(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
 
     Output layout matches ``all_to_all_baseline``: slab j = data from rank j.
     """
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     if n & (n - 1):

@@ -18,8 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from . import compat
-
 
 def gpipe(stage_fn: Callable, x_mb: jnp.ndarray, *, axis: str,
           n_micro: int) -> jnp.ndarray:
@@ -33,7 +31,7 @@ def gpipe(stage_fn: Callable, x_mb: jnp.ndarray, *, axis: str,
     the microbatch it holds and passes the result to the next stage via
     collective-permute (the bubble is (pp-1)/n_micro as usual).
     """
-    pp = compat.axis_size(axis)
+    pp = lax.axis_size(axis)
     stage = lax.axis_index(axis)
     perm = [(i, i + 1) for i in range(pp - 1)]
 

@@ -3,10 +3,10 @@
 Every HBD model's ``evaluate_batch`` kernel is re-expressed as a pure
 ``jax.numpy`` function over ONE snapshot mask, composed under ``jax.vmap``
 over the snapshot axis and ``jax.jit`` over the whole (architectures x
-snapshots x TP sizes) grid.  On multi-device hosts the snapshot axis is
-sharded across all devices with ``shard_map`` (via the
-``repro.parallel.compat`` shims), so million-snapshot sweeps scale with the
-device count.  Chunks are device-resident and their input buffers donated,
+snapshots x TP sizes) grid.  When the engine has several devices
+(``repro.runtime.engine_devices``) the snapshot axis is sharded across them
+with ``jax.shard_map``, so million-snapshot sweeps scale with the device
+count.  Chunks are device-resident and their input buffers donated,
 keeping peak memory at ~one chunk regardless of sweep size.
 
 Guarantees (enforced by ``tests/test_jax_backend.py``):
@@ -15,11 +15,13 @@ Guarantees (enforced by ``tests/test_jax_backend.py``):
     on device (all grid quantities fit comfortably) and are widened to the
     engine's int64 grids on the host;
   * deterministic results independent of chunking and device count;
-  * for :class:`~repro.sim.scenario.CounterIIDSnapshots` specs, fault masks
-    are generated *on device* with ``jax.random`` key-splitting (one
-    ``fold_in`` per snapshot index) and match the NumPy mirror in
-    ``repro.core.prng`` exactly, so the two backends agree even when the
-    JAX path never materializes a host mask matrix.
+  * fault masks come from the host: with ``jax_threefry_partitionable``
+    on (the default of the installed JAX 0.9), ``jax.random`` no longer
+    draws the canonical counter stream of ``repro.core.prng``, so
+    :func:`device_draws_canonical` is False and ``run_sweep`` streams
+    host-drawn :class:`~repro.sim.scenario.CounterIIDSnapshots` masks to
+    the device block by block.  The device draw (one ``fold_in`` per
+    snapshot index) runs only where that flag is off.
 """
 
 from __future__ import annotations
@@ -36,7 +38,6 @@ try:  # keep repro.sim importable on numpy-only installs
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from ..parallel.compat import make_mesh, shard_map
     HAVE_JAX = True
     _IMPORT_ERROR: Optional[BaseException] = None
 except Exception as e:  # pragma: no cover - exercised on jax-free installs
@@ -45,6 +46,7 @@ except Exception as e:  # pragma: no cover - exercised on jax-free installs
 
 from .. import obs
 from ..core import prng as cprng
+from ..runtime import engine_devices, snapshot_mesh
 from ..core.hbd_models import (BigSwitch, HBDModel, InfiniteHBDModel,
                                NVLModel, SiPRingModel, TPUv4Model)
 
@@ -291,13 +293,6 @@ def _counter_mask(gen: MaskGen, idx):
 _GRID_CACHE: Dict[Tuple, Callable] = {}
 
 
-def _mesh():
-    devs = jax.devices()
-    if len(devs) > 1:
-        return make_mesh((len(devs),), (_SNAP_AXIS,))
-    return None
-
-
 def _grid_fn(models: Sequence[HBDModel], tps: Sequence[int], mesh,
              gen: Optional[MaskGen], width: int) -> Callable:
     """Jitted ``(rows, W) bool -> (rows, A, 2, T) int32`` grid evaluator.
@@ -309,8 +304,7 @@ def _grid_fn(models: Sequence[HBDModel], tps: Sequence[int], mesh,
     benchmark's warm-up + timed call) reuse one compiled executable.
     """
     key = (tuple(_model_key(m) for m in models),
-           tuple(int(t) for t in tps), width,
-           None if mesh is None else mesh.devices.size,
+           tuple(int(t) for t in tps), width, mesh,
            None if gen is None else (gen.num_nodes,
                                      cprng.ratio_threshold(gen.fault_ratio),
                                      gen.seed))
@@ -333,8 +327,8 @@ def _grid_fn(models: Sequence[HBDModel], tps: Sequence[int], mesh,
 
     batched = jax.vmap(per_snapshot)
     if mesh is not None:
-        batched = shard_map(batched, mesh=mesh,
-                            in_specs=P(_SNAP_AXIS), out_specs=P(_SNAP_AXIS))
+        batched = jax.shard_map(batched, mesh=mesh, in_specs=P(_SNAP_AXIS),
+                                out_specs=P(_SNAP_AXIS))
     fn = jax.jit(batched, donate_argnums=0)
     _GRID_CACHE[key] = fn
     return fn
@@ -370,7 +364,7 @@ class GridEvaluator:
         self.tps = [int(t) for t in tps]
         self.width = width
         self.gen = gen
-        self.mesh = _mesh()
+        self.mesh = snapshot_mesh(_SNAP_AXIS)
         self.ndev = 1 if self.mesh is None else self.mesh.devices.size
         self.sharding = (None if self.mesh is None
                          else NamedSharding(self.mesh, P(_SNAP_AXIS)))
@@ -481,7 +475,7 @@ def counter_masks_device(gen: MaskGen) -> np.ndarray:
 
 
 def num_devices() -> int:
-    return len(jax.devices()) if HAVE_JAX else 0
+    return len(engine_devices()) if HAVE_JAX else 0
 
 
 __all__ = [

@@ -13,16 +13,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.parallel.compat import shard_map
+from repro.runtime import make_mesh
 
 
 def check_collectives():
     from repro.parallel.collectives import (
         all_to_all_baseline, binary_exchange_all_to_all, ring_all_gather,
         ring_all_reduce, ring_reduce_scatter)
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     x = jnp.arange(8 * 16 * 3, dtype=jnp.float32).reshape(8, 16, 3)
-    sm = lambda f: shard_map(f, mesh=mesh, in_specs=P("model"),
+    sm = lambda f: jax.shard_map(f, mesh=mesh, in_specs=P("model"),
                              out_specs=P("model"))
     ring = jax.jit(sm(lambda xl: ring_all_reduce(xl, "model", impl="ring")))(x)
     psum = jax.jit(sm(lambda xl: ring_all_reduce(xl, "model", impl="psum")))(x)
@@ -47,7 +47,7 @@ def check_sharded_equals_unsharded():
     from repro.parallel.sharding import mesh_axes, parallel_rules
     from repro.parallel.specs import param_pspecs, shardings_for
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = mesh_axes(multi_pod=False)
     for arch in ("deepseek-67b", "mixtral-8x7b", "mamba2-780m"):
         cfg = get_arch(arch).reduced()
@@ -83,7 +83,7 @@ def check_moe_tp_vs_ep():
     from repro.parallel.sharding import mesh_axes, parallel_rules
     from repro.parallel.specs import param_pspecs, shardings_for
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = mesh_axes(multi_pod=False)
     cfg = dataclasses.replace(get_arch("mixtral-8x7b").reduced(),
                               capacity_factor=16.0)
@@ -119,7 +119,7 @@ def check_ring_allreduce_in_model():
     from repro.parallel.sharding import mesh_axes, parallel_rules
     from repro.parallel.specs import param_pspecs, shardings_for
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = mesh_axes(multi_pod=False)
     cfg = dataclasses.replace(get_arch("mixtral-8x7b").reduced(),
                               capacity_factor=16.0)
@@ -146,7 +146,7 @@ def check_ring_allreduce_in_model():
 def check_gpipe():
     """GPipe over a 4-stage 'pod' axis == sequential stage application."""
     from repro.parallel.pipeline import gpipe
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     n_micro, mb, dim = 6, 2, 8
     ws = jax.random.normal(jax.random.PRNGKey(0), (4, dim, dim)) * 0.3
 
@@ -159,7 +159,7 @@ def check_gpipe():
     def run(xr):
         return gpipe(stage_fn, xr, axis="pod", n_micro=n_micro)
 
-    out = jax.jit(shard_map(run, mesh=mesh, in_specs=P(),
+    out = jax.jit(jax.shard_map(run, mesh=mesh, in_specs=P(),
                             out_specs=P(), check_vma=False))(x_mb)
     # reference: apply the 4 stages sequentially
     ref = x_mb
