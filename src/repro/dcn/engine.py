@@ -257,8 +257,10 @@ def run_dcn_sweep(spec: DcnSpec, *, backend: str = "auto",
                     stacked, cfg, variant, int(tp), job, backend=chosen,
                     greedy_seed=spec.greedy_seed,
                     chunk_snapshots=chunk_snapshots)
-                counts = batched_pair_counts(bp, cfg.nodes_per_tor,
-                                             cfg.agg_domain)
+                with obs.span("dcn.pair_counts", variant=variant,
+                              tp=int(tp), snapshots=len(stacked)):
+                    counts = batched_pair_counts(bp, cfg.nodes_per_tor,
+                                                 cfg.agg_domain)
                 grid_shape = (r_count, spec.samples)
                 for key in _COUNT_KEYS:
                     grids[key][vi, :, :, ti] = counts[key].reshape(

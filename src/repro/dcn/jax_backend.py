@@ -34,6 +34,7 @@ except Exception as e:  # pragma: no cover - exercised on jax-free installs
     HAVE_JAX = False
     _IMPORT_ERROR = e
 
+from .. import obs
 from ..runtime import engine_devices, snapshot_mesh
 from .kernel import BatchedPlacement, FatTreeConfig
 
@@ -90,7 +91,8 @@ def _snapshot_fn(cfg: FatTreeConfig, tp_sizes: Sequence[int],
     t_idx = jnp.arange(tpd, dtype=jnp.int32)[None, None, :]
     node_of = d_idx * agg + t_idx * p + i_idx           # (D, P, Tpd)
 
-    def fn(mask):
+    # the name is the program's: ``jit_place_fat_tree`` in a device trace
+    def place_fat_tree(mask):
         grid = mask[:d * tpd * p].reshape(d, tpd, p)
         raw = grid.transpose(0, 2, 1)                   # (D, P, Tpd)
         aligned = jnp.broadcast_to(grid.any(axis=2, keepdims=True),
@@ -182,7 +184,7 @@ def _snapshot_fn(cfg: FatTreeConfig, tp_sizes: Sequence[int],
             out.append({"members": members, "feasible": feasible,
                         "n_constraints": jnp.where(feasible, best, -1)})
         return out
-    return fn
+    return place_fat_tree
 
 
 # ------------------------------------------------------------- grid runner
@@ -252,22 +254,30 @@ def fat_tree_placements(masks: np.ndarray, cfg: FatTreeConfig,
     for lo in range(0, snaps, chunk):
         hi = min(lo + chunk, snaps)
         rows = hi - lo
-        padded = -(-rows // ndev) * ndev
-        block = masks[lo:hi]
-        if padded != rows:
-            block = np.concatenate(
-                [block, np.zeros((padded - rows, width), bool)])
-        arg = (jnp.asarray(block) if sharding is None
-               else jax.device_put(block, sharding))
+        with obs.span("dcn.jax.put", rows=rows) as sp:
+            padded = -(-rows // ndev) * ndev
+            block = masks[lo:hi]
+            if padded != rows:
+                block = np.concatenate(
+                    [block, np.zeros((padded - rows, width), bool)])
+            sp.set(bytes=block.nbytes)
+            arg = (jnp.asarray(block) if sharding is None
+                   else jax.device_put(block, sharding))
         with warnings.catch_warnings():
             warnings.filterwarnings("ignore", message=".*onat.*buffer.*")
             res = fn(arg)
-        for ti in range(len(tps)):
-            outs[ti].members[lo:hi] = np.asarray(
-                res[ti]["members"][:rows], dtype=np.int32)
-            outs[ti].feasible[lo:hi] = np.asarray(res[ti]["feasible"][:rows])
-            outs[ti].n_constraints[lo:hi] = np.asarray(
-                res[ti]["n_constraints"][:rows], dtype=np.int64)
+        # the fetch span times the copies alone, not the wait for the
+        # program
+        jax.block_until_ready(res)
+        with obs.span("dcn.jax.fetch", rows=rows) as sp:
+            fetched = 0
+            for ti, on_device in enumerate(res):
+                host = {k: np.asarray(v) for k, v in on_device.items()}
+                fetched += sum(v.nbytes for v in host.values())
+                outs[ti].members[lo:hi] = host["members"][:rows]
+                outs[ti].feasible[lo:hi] = host["feasible"][:rows]
+                outs[ti].n_constraints[lo:hi] = host["n_constraints"][:rows]
+            sp.set(bytes=fetched)
     return outs
 
 

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+from .. import obs
 from .engine import DcnSweepResult
 from .traffic import LLAMA3_70B, dp_tp_bytes
 
@@ -21,37 +22,39 @@ def traffic_tables(result: DcnSweepResult, *, dp_bytes: Optional[float] = None,
     snapshot reports ``None`` shares instead of a fake zero.
     """
     from ..core.orchestrator import traffic_volume_shares
-    rows = []
-    for ti, tp in enumerate(result.tp_sizes):
-        if dp_bytes is None or tp_bytes is None:
-            db, tb = dp_tp_bytes(LLAMA3_70B, int(tp), dp_size)
-        else:
-            db, tb = dp_bytes, tp_bytes
-        # slice this TP's column before the float share arithmetic (the
-        # full (V, R, S, T) grids would be recomputed once per TP)
-        shares = traffic_volume_shares(
-            result.dp_pairs[..., ti], result.crossing_pairs[..., ti],
-            result.crossing_pod_pairs[..., ti],
-            result.groups[..., ti] * int(result.group_nodes[ti]), db, tb)
-        for vi, variant in enumerate(result.variants):
-            for ri, ratio in enumerate(result.spec.fault_ratios):
-                feas = result.feasible[vi, ri, :, ti]
-                row = {
-                    "variant": variant, "fault_ratio": float(ratio),
-                    "tp_size": int(tp),
-                    "feasible_share": float(feas.mean()) if feas.size else 0.0,
-                }
-                for key in ("cross_tor_share", "cross_pod_share",
-                            "dp_cross_share"):
-                    cell = shares[key][vi, ri][feas]
-                    row[f"mean_{key}"] = (float(cell.mean()) if cell.size
-                                          else None)
-                if variant == "orchestrated":
-                    nc = result.n_constraints[ri, :, ti]
-                    nc = nc[nc >= 0]
-                    row["mean_constraints"] = (float(nc.mean()) if nc.size
-                                               else None)
-                rows.append(row)
+    with obs.span("dcn.tables.traffic_tables"):
+        rows = []
+        for ti, tp in enumerate(result.tp_sizes):
+            if dp_bytes is None or tp_bytes is None:
+                db, tb = dp_tp_bytes(LLAMA3_70B, int(tp), dp_size)
+            else:
+                db, tb = dp_bytes, tp_bytes
+            # slice this TP's column before the float share arithmetic (the
+            # full (V, R, S, T) grids would be recomputed once per TP)
+            shares = traffic_volume_shares(
+                result.dp_pairs[..., ti], result.crossing_pairs[..., ti],
+                result.crossing_pod_pairs[..., ti],
+                result.groups[..., ti] * int(result.group_nodes[ti]), db, tb)
+            for vi, variant in enumerate(result.variants):
+                for ri, ratio in enumerate(result.spec.fault_ratios):
+                    feas = result.feasible[vi, ri, :, ti]
+                    row = {
+                        "variant": variant, "fault_ratio": float(ratio),
+                        "tp_size": int(tp),
+                        "feasible_share": (float(feas.mean()) if feas.size
+                                           else 0.0),
+                    }
+                    for key in ("cross_tor_share", "cross_pod_share",
+                                "dp_cross_share"):
+                        cell = shares[key][vi, ri][feas]
+                        row[f"mean_{key}"] = (float(cell.mean()) if cell.size
+                                              else None)
+                    if variant == "orchestrated":
+                        nc = result.n_constraints[ri, :, ti]
+                        nc = nc[nc >= 0]
+                        row["mean_constraints"] = (float(nc.mean()) if nc.size
+                                                   else None)
+                    rows.append(row)
     return rows
 
 

@@ -19,6 +19,26 @@ Typical use (the engines already do this)::
 Enable collection with ``obs.enable()`` or ``REPRO_TRACE=1`` (atexit
 export to ``REPRO_TRACE_PATH``, default ``repro.trace.json``), then
 ``obs.export(path)`` / ``obs.summary()``.
+
+Enabled, every span of a process that has imported ``jax`` is mirrored as
+a ``jax.profiler.TraceAnnotation`` of the same name, so a
+``jax.profiler`` trace shows the program's host layers on its own clock
+beside the device planes (:mod:`repro.obs.telemetry`).
+
+Spans of the path from a spec to its tables, outermost first:
+
+  * sweep (``repro.sim``): ``sim.models`` (building the architecture
+    models), ``sim.run_sweep``, ``prng.counter_fault_masks`` (host mask
+    draw), ``sim.stream.block`` / ``sim.evaluate_masks``,
+    ``sim.jax.setup`` (evaluator and totals, once per ``sweep_grids``
+    call, so once per block on the streamed path), ``sim.jax.eval_block``
+    holding ``sim.jax.put`` (host to device) and ``sim.jax.fetch`` (device
+    to host, int64 unpacking), then ``sim.tables.waste_table`` /
+    ``sim.tables.max_job_table``;
+  * DCN (``repro.dcn``): ``dcn.run_dcn_sweep``, ``dcn.evaluate_placements``
+    per variant and TP (the orchestrated one holding ``dcn.jax.put`` /
+    ``dcn.jax.fetch`` per block), ``dcn.pair_counts`` per variant and TP,
+    then ``dcn.tables.traffic_tables``.
 """
 
 # import the .export submodule eagerly: a first lazy import (inside

@@ -23,6 +23,14 @@ because every instrumented site operates at *block* granularity (one span
 per ~1024-snapshot chunk, one counter bump per mask batch), never per
 snapshot.
 
+**Profiler mirror.**  Enabled, a span whose process has already imported
+``jax`` also enters a ``jax.profiler.TraceAnnotation`` of the same name
+beside its ``perf_counter_ns`` reads, so under ``jax.profiler`` every
+span appears on the profiler's host timeline next to the device planes,
+on the profiler's clock.  One anchor annotation with a ``perf_counter_ns``
+read inside it maps that clock onto the spans' (``tests/test_obs.py``
+holds the two within 1 ms).  This module never imports JAX itself.
+
 Enabling: programmatic (``obs.enable()`` / ``obs.disable()``) or via the
 ``REPRO_TRACE`` environment variable (any value but ``0``/``false``/``off``
 enables collection at import and registers an atexit export to
@@ -35,6 +43,7 @@ Perfetto-loadable trace with zero code changes.  Export lives in
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional, Tuple
@@ -92,7 +101,8 @@ class Span:
     latency and GPU delta after the replan runs).
     """
 
-    __slots__ = ("_tel", "name", "cat", "attrs", "start_ns", "child_ns")
+    __slots__ = ("_tel", "name", "cat", "attrs", "start_ns", "child_ns",
+                 "_mirror")
 
     def __init__(self, tel: "Telemetry", name: str, cat: str,
                  attrs: Optional[dict]):
@@ -102,6 +112,7 @@ class Span:
         self.attrs = attrs
         self.start_ns = 0
         self.child_ns = 0
+        self._mirror = None
 
     def set(self, **attrs) -> "Span":
         if self.attrs is None:
@@ -113,11 +124,19 @@ class Span:
     def __enter__(self) -> "Span":
         stack = self._tel._stack()
         stack.append(self)
+        jax = sys.modules.get("jax")
+        if jax is not None:
+            # the profiler mirror (module docstring): a host event of the
+            # same name on the profiler's clock
+            self._mirror = jax.profiler.TraceAnnotation(self.name)
+            self._mirror.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur_ns = time.perf_counter_ns() - self.start_ns
+        if self._mirror is not None:
+            self._mirror.__exit__(*exc)
         stack = self._tel._stack()
         # tolerate a disable() between enter and exit: only pop ourselves
         if stack and stack[-1] is self:

@@ -245,7 +245,9 @@ def run_sweep(spec: ScenarioSpec, *, masks: Optional[np.ndarray] = None,
     module docstring); the grids are bit-for-bit identical either way.
     """
     if models is None:
-        models = spec.models()
+        with obs.span("sim.models") as sp:
+            models = spec.models()
+            sp.set(models=len(models))
     names = [m.name for m in models]
     tps = np.asarray(spec.tp_sizes, dtype=np.int64)
     chosen = resolve_backend(backend, models)

@@ -27,7 +27,6 @@ Guarantees (enforced by ``tests/test_jax_backend.py``):
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Callable, Dict, Optional, Sequence, Tuple, Type
 
@@ -314,10 +313,16 @@ def _grid_fn(models: Sequence[HBDModel], tps: Sequence[int], mesh,
         return fn
     obs.count("sim.jax.jit_cache_miss")
 
-    kernels = [_builder_for(m)(m, tps) for m in models]
+    kernels = [(m.name, _builder_for(m)(m, tps)) for m in models]
 
     def eval_mask(mask):
-        return jnp.stack([jnp.stack(kfn(mask)) for kfn in kernels])
+        # each kernel's ops carry its architecture's name as their scope
+        # in the compiled program (and so in a device trace)
+        out = []
+        for name, kfn in kernels:
+            with jax.named_scope(name):
+                out.append(jnp.stack(kfn(mask)))
+        return jnp.stack(out)
 
     if gen is None:
         per_snapshot = eval_mask
@@ -382,38 +387,44 @@ class GridEvaluator:
         evaluator was built with ``gen``, a ``(rows,) int32`` vector of
         counter-stream snapshot indices.  Rows are padded on the tail to a
         device-count multiple and the pad rows discarded.
+
+        Spans: ``sim.jax.eval_block`` around the whole block, inside it
+        ``sim.jax.put`` (padding and the copy to the device) and
+        ``sim.jax.fetch`` (the copy back and the int64 unpacking, after
+        the program has finished).  The rest of ``eval_block`` is the wait
+        for the transfer tail and the program.  The method takes no
+        timings of its own.
         """
         rows = block.shape[0]
-        with obs.span("sim.jax.eval_block", rows=rows,
-                      devices=self.ndev) as sp:
-            padded = -(-rows // self.ndev) * self.ndev
-            if padded != rows:                 # pad the tail chunk only
-                if self.gen is None:
-                    block = np.concatenate(
-                        [block, np.zeros((padded - rows, self.width), bool)])
-                else:
-                    block = np.concatenate(
-                        [block, block[-1] + 1
-                         + np.arange(padded - rows, dtype=np.int32)])
-            # one transfer straight into the sharded layout (device_put from
-            # host numpy) -- no intermediate full copy on the default device
-            arg = (jnp.asarray(block) if self.sharding is None
-                   else jax.device_put(block, self.sharding))
-            t0 = time.perf_counter()
+        with obs.span("sim.jax.eval_block", rows=rows, devices=self.ndev):
+            with obs.span("sim.jax.put", rows=rows) as sp:
+                padded = -(-rows // self.ndev) * self.ndev
+                if padded != rows:             # pad the tail chunk only
+                    if self.gen is None:
+                        block = np.concatenate(
+                            [block,
+                             np.zeros((padded - rows, self.width), bool)])
+                    else:
+                        block = np.concatenate(
+                            [block, block[-1] + 1
+                             + np.arange(padded - rows, dtype=np.int32)])
+                sp.set(bytes=block.nbytes)
+                # one transfer straight into the sharded layout (device_put
+                # from host numpy) -- no intermediate full copy on the
+                # default device
+                arg = (jnp.asarray(block) if self.sharding is None
+                       else jax.device_put(block, self.sharding))
             with warnings.catch_warnings():
                 # bool/int32 donation can't alias int32 outputs; the
                 # donation still releases the chunk buffer eagerly, which
                 # is the point
                 warnings.filterwarnings("ignore", message=".*onat.*buffer.*")
-                out = np.asarray(self.fn(arg))     # (padded, A, 2, T)
-            elapsed = time.perf_counter() - t0
-            obs.count("sim.jax.donated_blocks")
-            if elapsed > 0:
-                rate = rows / elapsed
-                sp.set(snaps_per_sec=round(rate, 1))
-                obs.gauge("sim.jax.snaps_per_sec", rate)
-            return (out[:rows, :, 0].transpose(1, 0, 2).astype(np.int64),
-                    out[:rows, :, 1].transpose(1, 0, 2).astype(np.int64))
+                out = self.fn(arg)                 # (padded, A, 2, T)
+            jax.block_until_ready(out)
+            with obs.span("sim.jax.fetch", rows=rows):
+                out = np.asarray(out)
+                return (out[:rows, :, 0].transpose(1, 0, 2).astype(np.int64),
+                        out[:rows, :, 1].transpose(1, 0, 2).astype(np.int64))
 
 
 def sweep_grids(models: Sequence[HBDModel], tps: Sequence[int], *,
@@ -441,8 +452,9 @@ def sweep_grids(models: Sequence[HBDModel], tps: Sequence[int], *,
     if snaps == 0:  # NumPy engine's zero-snapshot grid keeps totals at zero
         return total, faulty, placed
 
-    ev = GridEvaluator(models, tps, width, gen=gen)
-    total[:] = ev.totals()
+    with obs.span("sim.jax.setup", rows=snaps):
+        ev = GridEvaluator(models, tps, width, gen=gen)
+        total[:] = ev.totals()
     chunk = max(1, chunk_snapshots)
     chunk = -(-chunk // ev.ndev) * ev.ndev     # multiple of the device count
     for lo in range(0, snaps, chunk):

@@ -22,32 +22,34 @@ from .engine import SweepResult
 
 def waste_table(result: SweepResult) -> List[Dict]:
     """Per (architecture, TP): mean/P50/P99 waste ratio over snapshots."""
-    waste = result.waste_ratio
-    rows = []
-    for ai, name in enumerate(result.names):
-        for ti, tp in enumerate(result.tp_sizes):
-            mean, p50, p99 = waste_stats(waste[ai, :, ti])
-            rows.append({
-                "architecture": name, "tp_size": int(tp),
-                "mean_waste": mean, "p50_waste": p50, "p99_waste": p99,
-            })
+    with obs.span("sim.tables.waste_table", rows=result.num_snapshots):
+        waste = result.waste_ratio
+        rows = []
+        for ai, name in enumerate(result.names):
+            for ti, tp in enumerate(result.tp_sizes):
+                mean, p50, p99 = waste_stats(waste[ai, :, ti])
+                rows.append({
+                    "architecture": name, "tp_size": int(tp),
+                    "mean_waste": mean, "p50_waste": p50, "p99_waste": p99,
+                })
     return rows
 
 
 def max_job_table(result: SweepResult, percentile: float = 5.0) -> List[Dict]:
     """Per (architecture, TP): P5 of placeable GPUs -- the job scale a long
     run could hold through ~95% of the trace (Fig. 15)."""
-    rows = []
-    for ai, name in enumerate(result.names):
-        for ti, tp in enumerate(result.tp_sizes):
-            gpus = percentile_capacity(result.placed_gpus[ai, :, ti],
-                                       percentile)
-            total = int(result.total_gpus[ai, ti])
-            rows.append({
-                "architecture": name, "tp_size": int(tp),
-                "max_job_gpus": gpus,
-                "fraction": gpus / total if total else 0.0,
-            })
+    with obs.span("sim.tables.max_job_table", rows=result.num_snapshots):
+        rows = []
+        for ai, name in enumerate(result.names):
+            for ti, tp in enumerate(result.tp_sizes):
+                gpus = percentile_capacity(result.placed_gpus[ai, :, ti],
+                                           percentile)
+                total = int(result.total_gpus[ai, ti])
+                rows.append({
+                    "architecture": name, "tp_size": int(tp),
+                    "max_job_gpus": gpus,
+                    "fraction": gpus / total if total else 0.0,
+                })
     return rows
 
 

@@ -10,7 +10,9 @@ The load-bearing contracts:
     sweep grids are bit-identical with telemetry on and off;
   * a collected trace survives the full export pipeline: spans/counters ->
     Chrome-trace JSON -> ``tools/trace_report.py`` parse, with the report's
-    aggregates agreeing with ``Telemetry.summary()``.
+    aggregates agreeing with ``Telemetry.summary()``;
+  * every host layer from spec to tables has its span, and the spans land
+    on the ``jax.profiler`` clock within 1 ms of their own.
 """
 
 import json
@@ -27,9 +29,10 @@ from repro.churn import ChurnJob, ChurnSpec, control_plane_replay, \
     monte_carlo_replay
 from repro.core.control_plane import ClusterManager
 from repro.obs import NULL_SPAN, Progress
-from repro.sim import jax_backend
+from repro.sim import DcnSpec, jax_backend, run_dcn_sweep, traffic_tables
 from repro.sim.engine import evaluate_mask_stream, run_sweep
 from repro.sim.scenario import CounterIIDSnapshots, ScenarioSpec
+from repro.sim.tables import max_job_table, waste_table
 
 ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT / "tools"))
@@ -253,7 +256,129 @@ def test_jax_jit_cache_counters(tel):
     assert second.get("sim.jax.jit_cache_hit", 0) >= 1
     assert second["sim.jax.jit_cache_miss"] == \
         first["sim.jax.jit_cache_miss"]
-    assert second.get("sim.jax.donated_blocks", 0) >= 1
+    assert obs.summary()["spans"]["sim.jax.eval_block"]["count"] >= 1
+
+
+# ------------------------------------------------- host layers of a spec
+
+
+def _named(tel, name):
+    return [r for r in tel.spans if r.name == name]
+
+
+def _within(inner, outer) -> bool:
+    return (outer.start_ns <= inner.start_ns
+            and inner.start_ns + inner.dur_ns
+            <= outer.start_ns + outer.dur_ns)
+
+
+@pytest.mark.skipif(not jax_backend.HAVE_JAX, reason="jax unavailable")
+def test_sweep_host_layer_spans(tel):
+    # 64 nodes, two blocks of 32 host-drawn snapshots
+    result = run_sweep(_spec(64, num_nodes=64), backend="jax",
+                       chunk_snapshots=32)
+    waste_table(result)
+    max_job_table(result)
+    [models] = _named(tel, "sim.models")
+    assert models.attrs == {"models": len(ARCHES)}
+    blocks = _named(tel, "sim.jax.eval_block")
+    assert [b.attrs["rows"] for b in blocks] == [32, 32]
+    # one evaluator per block on the streamed path
+    assert [s.attrs for s in _named(tel, "sim.jax.setup")] == \
+        [{"rows": 32}] * 2
+    puts, fetches = _named(tel, "sim.jax.put"), _named(tel, "sim.jax.fetch")
+    assert len(puts) == len(fetches) == 2
+    for block, put, fetch in zip(blocks, puts, fetches):
+        assert put.attrs == {"rows": 32, "bytes": 32 * 64}
+        assert fetch.attrs == {"rows": 32}
+        for inner in (put, fetch):
+            assert _within(inner, block) and inner.depth == block.depth + 1
+        assert put.start_ns + put.dur_ns <= fetch.start_ns
+    for name in ("sim.tables.waste_table", "sim.tables.max_job_table"):
+        [table] = _named(tel, name)
+        assert table.attrs == {"rows": 64}
+    # nothing left of the per-block rate instruments
+    assert "sim.jax.snaps_per_sec" not in obs.summary()["gauges"]
+    assert all("snaps_per_sec" not in b.attrs for b in blocks)
+
+
+@pytest.mark.skipif(not jax_backend.HAVE_JAX, reason="jax unavailable")
+def test_dcn_host_layer_spans(tel):
+    spec = DcnSpec(num_nodes=256, fault_ratios=(0.0, 0.05), samples=4,
+                   tp_sizes=(16, 32), agg_domain=64, seed=2)
+    # 2 ratios x 4 snapshots = 8 rows: two blocks of 4 per TP size
+    result = run_dcn_sweep(spec, backend="jax", chunk_snapshots=4)
+    puts, fetches = _named(tel, "dcn.jax.put"), _named(tel, "dcn.jax.fetch")
+    assert len(puts) == len(fetches) == 2 * len(spec.tp_sizes)
+    assert all(p.attrs == {"rows": 4, "bytes": 4 * 256} for p in puts)
+    cfg = spec.config
+    # per snapshot: int32 members, a bool feasible, an int32 level
+    want = [4 * (cfg.need_groups(tp, spec.job_gpus(tp))
+                 * cfg.group_nodes(tp) * 4 + 1 + 4)
+            for tp in spec.tp_sizes for _ in range(2)]
+    assert [f.attrs for f in fetches] == [{"rows": 4, "bytes": b}
+                                          for b in want]
+    placements = [r for r in _named(tel, "dcn.evaluate_placements")
+                  if r.attrs["variant"] == "orchestrated"]
+    for inner in puts + fetches:
+        assert any(_within(inner, outer) for outer in placements)
+    pairs = _named(tel, "dcn.pair_counts")
+    assert sorted((p.attrs["variant"], p.attrs["tp"]) for p in pairs) == \
+        sorted((v, tp) for v in spec.variants for tp in spec.tp_sizes)
+    assert all(p.attrs["snapshots"] == 8 for p in pairs)
+    traffic_tables(result)
+    assert len(_named(tel, "dcn.tables.traffic_tables")) == 1
+
+
+@pytest.mark.skipif(not jax_backend.HAVE_JAX, reason="jax unavailable")
+def test_spans_mirror_onto_the_profiler_clock(tel, tmp_path):
+    import jax
+    spec = _spec(64, num_nodes=64)
+    run_sweep(spec, backend="jax", chunk_snapshots=32)   # compile first
+    obs.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        # the anchor as the chip benchmark takes it: a perf_counter_ns
+        # read just inside an annotation
+        with jax.profiler.TraceAnnotation("test.anchor"):
+            anchor_ns = time.perf_counter_ns()
+            run_sweep(spec, backend="jax", chunk_snapshots=32)
+    finally:
+        jax.profiler.stop_trace()
+    [path] = tmp_path.glob("**/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    host = [(e.name, e.start_ns, e.duration_ns) for plane in data.planes
+            if plane.name.startswith("/host:") for line in plane.lines
+            for e in line.events]
+    [anchor] = [start for name, start, _ in host if name == "test.anchor"]
+    shift = anchor_ns - anchor
+    mirrored = sorted((start + shift, dur) for name, start, dur in host
+                      if name == "sim.jax.eval_block")
+    recorded = sorted((r.start_ns, r.dur_ns)
+                      for r in _named(tel, "sim.jax.eval_block"))
+    assert len(mirrored) == len(recorded) == 2
+    for (m_start, m_dur), (r_start, r_dur) in zip(mirrored, recorded):
+        assert abs(m_start - r_start) < 1e6
+        assert abs(m_start + m_dur - r_start - r_dur) < 1e6
+
+
+@pytest.mark.skipif(not jax_backend.HAVE_JAX, reason="jax unavailable")
+def test_device_programs_carry_stable_names():
+    import jax
+    from repro.dcn import jax_backend as dcn_jax
+    models = _spec(4, num_nodes=64).models()
+    sweep = jax_backend._grid_fn(models, [16], None, None, 64).lower(
+        jax.ShapeDtypeStruct((4, 64), bool)).compile().as_text()
+    assert "HloModule jit_eval_mask" in sweep
+    for m in models:
+        # each architecture kernel's ops sit in a scope of its name,
+        # which vmap wraps: op_name="jit(eval_mask)/vmap(nvl-72)/..."
+        assert f"/vmap({m.name})/" in sweep, m.name
+    spec = DcnSpec(num_nodes=256, tp_sizes=(32,), agg_domain=64)
+    dcn = dcn_jax._grid_fn(spec.config, (32,), (spec.job_gpus(32),),
+                           None).lower(
+        jax.ShapeDtypeStruct((4, 256), bool)).compile().as_text()
+    assert "HloModule jit_place_fat_tree" in dcn
 
 
 # ------------------------------------------------- progress callbacks
