@@ -98,7 +98,6 @@ def _report(phase: str, setup_s: float, steady_s: float, items: int,
 def sweep_phase(scale: Scale) -> None:
     from repro.sim import (CounterIIDSnapshots, MODEL_REGISTRY, ScenarioSpec,
                            run_sweep)
-    from repro.sim import jax_backend
     spec = ScenarioSpec(
         num_nodes=scale.sweep_nodes,
         snapshots=CounterIIDSnapshots(fault_ratio=scale.fault_ratio,
@@ -117,8 +116,7 @@ def sweep_phase(scale: Scale) -> None:
         f"mean_waste_tp32[{name}]={waste[dev.index(name), :, dev.tp_index(32)].mean():.6f}"
         for name in ("infinitehbd-k3", "nvl-72"))
     _report("sweep", setup_s, steady_s, scale.sweep_samples, "snapshots",
-            masks="device" if jax_backend.device_draws_canonical()
-            else "host")
+            masks="device")
     print(f"sweep: {len(dev.names)} architectures x {dev.num_snapshots} "
           f"snapshots x TP {tuple(int(t) for t in dev.tp_sizes)} at "
           f"{spec.num_nodes} nodes == numpy; {sanity}", flush=True)

@@ -1,24 +1,24 @@
 """Counter-based PRNG: a NumPy mirror of JAX's threefry-2x32 stream.
 
-The batched scenario engine generates i.i.d. fault masks on-device with
-``jax.random`` (key-splitting per snapshot keeps generation chunk- and
-shard-invariant).  This module reimplements the exact same stream in pure
-NumPy so the NumPy backend produces bit-identical masks from the same seed:
+The batched scenario engine draws i.i.d. fault masks from a counter-based
+threefry stream (key-splitting per snapshot keeps generation chunk- and
+shard-invariant).  This module holds that stream in pure NumPy:
 
   * :func:`threefry_seed`     == ``jax.random.PRNGKey(seed)`` raw key data;
   * :func:`threefry_fold_in`  == ``jax.random.fold_in`` (threefry impl);
   * :func:`threefry_bits`     == ``jax.random.bits(key, (n,), uint32)``;
-  * :func:`counter_fault_masks` == the device-side mask generator in
-    ``repro.sim.jax_backend``.
+  * :func:`counter_fault_masks` == the device draw of
+    ``repro.sim.jax_backend`` (same keys, same lanes, same cipher).
 
 The mask itself is an integer-threshold comparison (``bits < round(ratio *
 2**32)``) rather than a float comparison, so backend equality never hinges
 on float rounding.  Both the "original" and "partitionable" threefry bit
 layouts are implemented (:func:`threefry_bits`), but the canonical mask
 stream of :func:`counter_fault_masks` is pinned to the original layout
-everywhere; the JAX backend only draws on device when the ambient config
-still produces that layout (``jax_backend.device_draws_canonical``) and
-falls back to these host masks otherwise.
+everywhere.  The JAX backend draws that stream on the device with its own
+threefry-2x32 over the explicit counters of :func:`counter_lanes` (the
+schedule constants below are the one copy), never with ``jax.random``, so
+the ``jax_threefry_partitionable`` flag cannot change it.
 """
 
 from __future__ import annotations
@@ -145,6 +145,20 @@ def ratio_threshold(ratio: float) -> int:
     return min(1 << 32, max(0, int(round(float(ratio) * (1 << 32)))))
 
 
+def counter_lanes(num_nodes: int) -> tuple:
+    """Per-row counter lanes ``(c0, c1)`` of the canonical mask stream.
+
+    The original threefry layout splits the flat iota ``[0..n-1]`` in half;
+    an odd width pads one zero counter at the end of ``c1``.  Lane ``j``
+    hashes ``(c0[j], c1[j])``: ``x0`` gives node ``j``, ``x1`` node
+    ``half + j``, and the pad lane's ``x1`` is dropped.
+    """
+    half = (num_nodes + 1) // 2
+    flat = np.arange(2 * half, dtype=_U32)
+    flat[num_nodes:] = 0                       # odd width pads one zero
+    return flat[:half], flat[half:]
+
+
 #: Row-block budget of the batched mask generator: lanes are processed in
 #: blocks of at most ``2**22`` counters so the uint32 working set stays at
 #: a few tens of MB regardless of the requested snapshot count.
@@ -160,9 +174,9 @@ def counter_fault_masks(num_nodes: int, node_fault_ratio: float,
     Row ``i`` depends only on ``(seed, start + i)`` -- key
     ``fold_in(seed_key, start + i)`` hashed over a per-node counter -- so
     the matrix is invariant under chunking and device sharding, and both
-    the JAX backend (on device, via ``jax.random``) and the streaming
-    engine (host, per chunk via ``start``) regenerate identical rows
-    without ever materializing the full matrix (see
+    the JAX backend (on device, the same cipher over the same lanes) and
+    the streaming engine (host, per chunk via ``start``) regenerate
+    identical rows without ever materializing the full matrix (see
     ``repro.sim.jax_backend.counter_masks_device``).
 
     The whole batch is generated as vectorized broadcast cipher calls over
@@ -173,11 +187,8 @@ def counter_fault_masks(num_nodes: int, node_fault_ratio: float,
 
     The canonical stream is pinned to the *original* threefry bit layout
     (``partitionable=False``) regardless of the environment, so a seeded
-    spec reproduces identically everywhere -- including numpy-only
-    installs and future JAX releases that flip the
-    ``jax_threefry_partitionable`` default (the JAX backend checks the
-    ambient flag and falls back to these host masks when the device draw
-    would not be canonical).
+    spec reproduces identically everywhere -- numpy-only installs and
+    every ``jax_threefry_partitionable`` setting included.
     """
     thresh = ratio_threshold(node_fault_ratio)
     if samples == 0 or num_nodes == 0:
@@ -198,10 +209,8 @@ def counter_fault_masks(num_nodes: int, node_fault_ratio: float,
             c0_row = np.zeros(num_nodes, _U32)
             c1_row = np.arange(num_nodes, dtype=_U32)
         else:
-            half = (num_nodes + 1) // 2
-            flat = np.arange(2 * half, dtype=_U32)
-            flat[num_nodes:] = 0               # odd width pads one zero
-            c0_row, c1_row = flat[:half], flat[half:]
+            c0_row, c1_row = counter_lanes(num_nodes)
+            half = c0_row.size
         for lo_r in range(0, samples, rows_per_block):
             hi_r = min(lo_r + rows_per_block, samples)
             rows = hi_r - lo_r
@@ -229,5 +238,5 @@ def counter_fault_masks(num_nodes: int, node_fault_ratio: float,
 __all__ = [
     "threefry2x32", "threefry_hash", "threefry_seed", "threefry_fold_in",
     "threefry_fold_in_batch", "threefry_bits", "ratio_threshold",
-    "counter_fault_masks",
+    "counter_lanes", "counter_fault_masks",
 ]

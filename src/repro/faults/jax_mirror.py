@@ -10,15 +10,19 @@ counter layout) on device, and :class:`JaxDraw` wires it behind the same
 named-sub-stream interface as :class:`repro.faults.base.NumpyDraw`.
 
 uint32 addition in jnp wraps modulo 2**32 by construction, so the cipher
-is exact without any errstate handling; key derivation (seed + fold_in)
-is a handful of host-side scalar hashes and reuses the NumPy mirror
-directly.  Import is gated: ``HAVE_JAX`` is False on numpy-only installs
-and :class:`JaxDraw` raises on construction there.
+is exact without any errstate handling.  :func:`threefry2x32_jnp` is the
+repository's one jnp cipher: it takes host or traced uint32 keys, scalar
+or broadcast against the counter lanes, so the sweep engine's device mask
+draw (``repro.sim.jax_backend``) runs it too.  The round schedule is
+``repro.core.prng``'s own.  Key derivation for :class:`JaxDraw` (seed +
+fold_in) is a handful of host-side scalar hashes and reuses the NumPy
+mirror directly.  Import is gated: ``HAVE_JAX`` is False on numpy-only
+installs and :class:`JaxDraw` raises on construction there.
 """
 
 from __future__ import annotations
 
-from ..core.prng import threefry_fold_in, threefry_seed
+from ..core.prng import _INJECT, _ROTATIONS, threefry_fold_in, threefry_seed
 
 try:
     import jax.numpy as jnp
@@ -27,16 +31,17 @@ except ImportError:                                    # pragma: no cover
     jnp = None
     HAVE_JAX = False
 
-# identical schedule constants to repro.core.prng
-_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
-_INJECT = ((1, 2, 1), (2, 0, 2), (0, 1, 3), (1, 2, 4), (2, 0, 5))
-
 
 def threefry2x32_jnp(k0, k1, c0, c1):
     """Threefry-2x32 on jnp uint32 lanes (20 rounds), bit-identical to
-    :func:`repro.core.prng.threefry2x32`."""
-    k0 = jnp.uint32(int(k0))
-    k1 = jnp.uint32(int(k1))
+    :func:`repro.core.prng.threefry2x32`.
+
+    ``k0``/``k1`` are uint32 key words: host scalars or arrays, or traced
+    values, broadcast against the counter lanes ``c0``/``c1`` (a
+    ``(rows, 1)`` key column hashes each row under its own key).
+    """
+    k0 = jnp.asarray(k0, jnp.uint32)
+    k1 = jnp.asarray(k1, jnp.uint32)
     ks = (k0, k1, k0 ^ k1 ^ jnp.uint32(0x1BD11BDA))
     x0 = jnp.asarray(c0, jnp.uint32) + ks[0]
     x1 = jnp.asarray(c1, jnp.uint32) + ks[1]

@@ -17,7 +17,8 @@ Two compute backends produce that grid bit-for-bit identically:
   * ``backend="numpy"`` -- the vectorized host kernels on each model;
   * ``backend="jax"``   -- ``repro.sim.jax_backend``: the same kernels as
     pure ``jax.numpy`` functions under ``jax.vmap``/``jax.jit`` with the
-    snapshot axis sharded across devices (million-snapshot sweeps).
+    snapshot axis sharded across devices (million-snapshot sweeps); a
+    ``CounterIIDSnapshots`` spec has its masks drawn on the device too.
 
 ``backend="auto"`` (the default) picks JAX whenever it is importable and
 every requested architecture has a jnp kernel; the ``REPRO_SWEEP_BACKEND``
@@ -256,27 +257,25 @@ def run_sweep(spec: ScenarioSpec, *, masks: Optional[np.ndarray] = None,
                   models=len(models)):
         if chosen == "jax" and masks is None \
                 and isinstance(spec.snapshots, CounterIIDSnapshots):
+            # counter-based spec: each block's masks are drawn on the
+            # device (bit-identical to the host stream) and never leave it
             from . import jax_backend
-            if jax_backend.device_draws_canonical():
-                # counter-based spec: draw the masks on device with
-                # jax.random (bit-identical to the host mirror, no host
-                # matrix needed)
-                gen = jax_backend.MaskGen(spec.snapshots.samples,
-                                          spec.num_nodes,
-                                          spec.snapshots.fault_ratio,
-                                          spec.snapshots.seed)
-                total, faulty, placed = jax_backend.sweep_grids(
-                    models, spec.tp_sizes, gen=gen,
-                    chunk_snapshots=chunk_snapshots)
-                return SweepResult(spec, names, tps, total, faulty, placed,
-                                   backend="jax")
+            sn = spec.snapshots
+            obs.count("sim.snapshots_evaluated", sn.samples)
+            gen = jax_backend.MaskGen(sn.samples, spec.num_nodes,
+                                      sn.fault_ratio, sn.seed)
+            total, faulty, placed = jax_backend.sweep_grids(
+                models, spec.tp_sizes, gen=gen,
+                chunk_snapshots=chunk_snapshots)
+            return SweepResult(spec, names, tps, total, faulty, placed,
+                               backend="jax")
 
         if masks is None:
             if isinstance(spec.snapshots, CounterIIDSnapshots):
                 # counter streams regenerate any row range bit-identically
                 # from a start offset, so stream the masks chunk by chunk --
                 # a million-snapshot spec never materializes the full host
-                # matrix on either backend
+                # matrix
                 sn = spec.snapshots
                 step = max(1, chunk_snapshots)
                 chunks = (counter_fault_masks(spec.num_nodes, sn.fault_ratio,

@@ -15,13 +15,14 @@ Guarantees (enforced by ``tests/test_jax_backend.py``):
     on device (all grid quantities fit comfortably) and are widened to the
     engine's int64 grids on the host;
   * deterministic results independent of chunking and device count;
-  * fault masks come from the host: with ``jax_threefry_partitionable``
-    on (the default of the installed JAX 0.9), ``jax.random`` no longer
-    draws the canonical counter stream of ``repro.core.prng``, so
-    :func:`device_draws_canonical` is False and ``run_sweep`` streams
-    host-drawn :class:`~repro.sim.scenario.CounterIIDSnapshots` masks to
-    the device block by block.  The device draw (one ``fold_in`` per
-    snapshot index) runs only where that flag is off.
+  * counter i.i.d. fault masks are drawn on the device: each block's rows
+    are hashed by the repository's jnp threefry-2x32
+    (``repro.faults.jax_mirror``) over the explicit counter lanes of
+    ``repro.core.prng.counter_lanes``, bit-identical to the host's
+    ``repro.core.prng.counter_fault_masks`` whatever
+    ``jax_threefry_partitionable`` says, and the block goes from the draw
+    into the grid program without leaving the device.  Every other mask
+    source is drawn on the host and copied over block by block.
 """
 
 from __future__ import annotations
@@ -45,6 +46,7 @@ except Exception as e:  # pragma: no cover - exercised on jax-free installs
 
 from .. import obs
 from ..core import prng as cprng
+from ..faults.jax_mirror import threefry2x32_jnp
 from ..runtime import engine_devices, snapshot_mesh
 from ..core.hbd_models import (BigSwitch, HBDModel, InfiniteHBDModel,
                                NVLModel, SiPRingModel, TPUv4Model)
@@ -54,7 +56,9 @@ _SNAP_AXIS = "snap"
 
 @dataclasses.dataclass(frozen=True)
 class MaskGen:
-    """Device-side counter-based mask generation request (no host matrix)."""
+    """A counter i.i.d. mask source drawn on the device (no host matrix):
+    rows ``0..samples-1`` of ``counter_fault_masks(num_nodes, fault_ratio,
+    samples, seed)``."""
 
     samples: int
     num_nodes: int
@@ -258,55 +262,63 @@ def require(models: Sequence[HBDModel]) -> None:
 
 # ------------------------------------------------------------- grid runner
 
-def device_draws_canonical() -> bool:
-    """True when ``jax.random.bits`` produces the canonical (original,
-    non-partitionable) threefry layout that ``repro.core.prng`` pins the
-    counter stream to.  When a JAX release flips the
-    ``jax_threefry_partitionable`` default, the engine falls back to
-    host-mirror mask generation rather than silently changing streams."""
-    if not HAVE_JAX:
-        return False
-    flag = getattr(jax.config, "jax_threefry_partitionable", None)
-    # fail closed: if the flag is gone (a future release dropping the
-    # original layout), assume the device stream is no longer canonical
-    return flag is not None and not bool(flag)
+_DRAW_CACHE: Dict[Tuple, Callable] = {}
 
 
-def _counter_mask(gen: MaskGen, idx):
-    """One snapshot's fault mask from the counter stream, on device.
+def _draw_fn(width: int, mesh) -> Callable:
+    """Jitted ``(keys (rows, 2) uint32, thresh uint32, full bool) ->
+    (rows, width) bool`` draw of counter-stream fault masks.
 
-    The single source of the ``jax.random`` draw scheme -- shared by the
-    fused sweep path and :func:`counter_masks_device` so the production
-    sweep can never desynchronize from what the equivalence tests (and the
-    NumPy mirror ``repro.core.prng.counter_fault_masks``) validate.
+    Row ``i`` hashes the lanes of ``repro.core.prng.counter_lanes`` under
+    its own key ``keys[i]`` (``fold_in(seed_key, snapshot_index)``); a node
+    is faulty where its bits are below ``thresh``, or everywhere when
+    ``full`` (a threshold of ``2**32``, which uint32 cannot hold).  Seed
+    and ratio are arguments, never part of the cache key, so every spec of
+    one width runs one executable (module ``jit_draw_counter_masks``).
     """
-    thresh = cprng.ratio_threshold(gen.fault_ratio)
-    if thresh >= (1 << 32):
-        return jnp.ones(gen.num_nodes, bool)
-    rk = jax.random.fold_in(
-        jax.random.PRNGKey(gen.seed, impl="threefry2x32"), idx)
-    bits = jax.random.bits(rk, (gen.num_nodes,), jnp.uint32)
-    return bits < jnp.uint32(thresh)
+    key = (width, mesh)
+    fn = _DRAW_CACHE.get(key)
+    if fn is not None:
+        return fn
+    c0, c1 = cprng.counter_lanes(width)
+    half = c0.size
+
+    def draw_counter_masks(keys, thresh, full):
+        x0, x1 = threefry2x32_jnp(keys[:, :1], keys[:, 1:], c0, c1)
+        # compared before they are joined: the cipher's fusion writes
+        # bools, not uint32 bits
+        return jnp.concatenate([x0 < thresh, x1[:, :width - half] < thresh],
+                               axis=1) | full
+
+    draw = draw_counter_masks
+    if mesh is not None:
+        draw = jax.shard_map(draw, mesh=mesh,
+                             in_specs=(P(_SNAP_AXIS), P(), P()),
+                             out_specs=P(_SNAP_AXIS))
+    fn = jax.jit(draw)
+    _DRAW_CACHE[key] = fn
+    return fn
+
+
+def _threshold_args(ratio: float) -> Tuple[np.uint32, np.bool_]:
+    """``(thresh, full)`` arguments of the draw for one fault ratio."""
+    thresh = cprng.ratio_threshold(ratio)
+    return np.uint32(min(thresh, 0xFFFFFFFF)), np.bool_(thresh >= 1 << 32)
 
 
 _GRID_CACHE: Dict[Tuple, Callable] = {}
 
 
 def _grid_fn(models: Sequence[HBDModel], tps: Sequence[int], mesh,
-             gen: Optional[MaskGen], width: int) -> Callable:
-    """Jitted ``(rows, W) bool -> (rows, A, 2, T) int32`` grid evaluator.
-
-    With ``gen`` set the argument is instead a ``(rows,) int32`` vector of
-    snapshot indices and masks are drawn on device via ``jax.random``.
+             width: int) -> Callable:
+    """Jitted ``(rows, W) bool -> (rows, A, 2, T) int32`` grid evaluator
+    (module ``jit_eval_mask``), its mask argument donated.
 
     Cached on the models' static configuration so repeated sweeps (and the
     benchmark's warm-up + timed call) reuse one compiled executable.
     """
     key = (tuple(_model_key(m) for m in models),
-           tuple(int(t) for t in tps), width, mesh,
-           None if gen is None else (gen.num_nodes,
-                                     cprng.ratio_threshold(gen.fault_ratio),
-                                     gen.seed))
+           tuple(int(t) for t in tps), width, mesh)
     fn = _GRID_CACHE.get(key)
     if fn is not None:
         obs.count("sim.jax.jit_cache_hit")
@@ -324,13 +336,7 @@ def _grid_fn(models: Sequence[HBDModel], tps: Sequence[int], mesh,
                 out.append(jnp.stack(kfn(mask)))
         return jnp.stack(out)
 
-    if gen is None:
-        per_snapshot = eval_mask
-    else:
-        def per_snapshot(idx):
-            return eval_mask(_counter_mask(gen, idx))
-
-    batched = jax.vmap(per_snapshot)
+    batched = jax.vmap(eval_mask)
     if mesh is not None:
         batched = jax.shard_map(batched, mesh=mesh, in_specs=P(_SNAP_AXIS),
                                 out_specs=P(_SNAP_AXIS))
@@ -373,7 +379,11 @@ class GridEvaluator:
         self.ndev = 1 if self.mesh is None else self.mesh.devices.size
         self.sharding = (None if self.mesh is None
                          else NamedSharding(self.mesh, P(_SNAP_AXIS)))
-        self.fn = _grid_fn(self.models, self.tps, self.mesh, gen, width)
+        self.fn = _grid_fn(self.models, self.tps, self.mesh, width)
+        if gen is not None:
+            self.draw = _draw_fn(width, self.mesh)
+            self.root = cprng.threefry_seed(gen.seed)
+            self.thresh = _threshold_args(gen.fault_ratio)
 
     def totals(self) -> np.ndarray:
         """Per-model (A, T) ``total_gpus`` grid (NumPy-engine identical)."""
@@ -384,36 +394,41 @@ class GridEvaluator:
         ``(A, rows, T)``.
 
         ``block`` is a ``(rows, width)`` bool mask matrix -- or, when the
-        evaluator was built with ``gen``, a ``(rows,) int32`` vector of
-        counter-stream snapshot indices.  Rows are padded on the tail to a
-        device-count multiple and the pad rows discarded.
+        evaluator was built with ``gen``, a ``(rows,)`` integer vector of
+        counter-stream snapshot indices, whose masks are drawn on the
+        device.  Rows are padded on the tail to a device-count multiple and
+        the pad rows discarded.
 
         Spans: ``sim.jax.eval_block`` around the whole block, inside it
-        ``sim.jax.put`` (padding and the copy to the device) and
-        ``sim.jax.fetch`` (the copy back and the int64 unpacking, after
-        the program has finished).  The rest of ``eval_block`` is the wait
-        for the transfer tail and the program.  The method takes no
-        timings of its own.
+        ``sim.jax.put`` (padding and the copy to the device: the mask
+        block, or each row's threefry key folded on the host),
+        ``prng.device_masks`` (the dispatch of the device draw, ``gen``
+        only) and ``sim.jax.fetch`` (the copy back and the int64
+        unpacking, after the program has finished).  The rest of
+        ``eval_block`` is the wait for the transfer tail and the programs.
+        The method takes no timings of its own.
         """
         rows = block.shape[0]
         with obs.span("sim.jax.eval_block", rows=rows, devices=self.ndev):
             with obs.span("sim.jax.put", rows=rows) as sp:
                 padded = -(-rows // self.ndev) * self.ndev
                 if padded != rows:             # pad the tail chunk only
-                    if self.gen is None:
-                        block = np.concatenate(
-                            [block,
-                             np.zeros((padded - rows, self.width), bool)])
-                    else:
-                        block = np.concatenate(
-                            [block, block[-1] + 1
-                             + np.arange(padded - rows, dtype=np.int32)])
+                    block = np.concatenate(
+                        [block, np.zeros((padded - rows,) + block.shape[1:],
+                                         block.dtype)])
+                if self.gen is not None:
+                    block = cprng.threefry_fold_in_batch(self.root, block)
                 sp.set(bytes=block.nbytes)
                 # one transfer straight into the sharded layout (device_put
                 # from host numpy) -- no intermediate full copy on the
                 # default device
                 arg = (jnp.asarray(block) if self.sharding is None
                        else jax.device_put(block, self.sharding))
+            if self.gen is not None:
+                with obs.span("prng.device_masks", samples=rows,
+                              nodes=self.width):
+                    arg = self.draw(arg, *self.thresh)
+                obs.count("prng.device_masks_drawn", rows)
             with warnings.catch_warnings():
                 # bool/int32 donation can't alias int32 outputs; the
                 # donation still releases the chunk buffer eagerly, which
@@ -435,7 +450,7 @@ def sweep_grids(models: Sequence[HBDModel], tps: Sequence[int], *,
     """Evaluate the grid on device; returns int64 (total, faulty, placed).
 
     Exactly one of ``masks`` (host snapshot matrix) and ``gen``
-    (device-side counter generation) must be provided.
+    (counter masks drawn on the device, block by block) must be provided.
     """
     if (masks is None) == (gen is None):
         raise ValueError("provide exactly one of masks= and gen=")
@@ -460,30 +475,28 @@ def sweep_grids(models: Sequence[HBDModel], tps: Sequence[int], *,
     for lo in range(0, snaps, chunk):
         hi = min(lo + chunk, snaps)
         block = (masks[lo:hi] if masks is not None
-                 else np.arange(lo, hi, dtype=np.int32))
+                 else np.arange(lo, hi, dtype=np.int64))
         f, p = ev.eval_block(block)
         faulty[:, lo:hi] = f
         placed[:, lo:hi] = p
     return total, faulty, placed
 
 
-def counter_masks_device(gen: MaskGen) -> np.ndarray:
-    """Device-side ``jax.random`` mask generation (for tests/tools): the
-    exact per-snapshot draw the fused sweep uses (shared
-    :func:`_counter_mask`), returned as a host bool matrix.  Bit-identical
-    to ``repro.core.prng.counter_fault_masks``."""
+def counter_masks_device(gen: MaskGen, start: int = 0) -> np.ndarray:
+    """Rows ``start..start+samples-1`` of the counter stream, drawn on the
+    device by the sweep's own draw program and returned as a host bool
+    matrix (for tests and tools).  Bit-identical to
+    ``repro.core.prng.counter_fault_masks(..., start=start)``."""
     if not HAVE_JAX:
         raise RuntimeError(f"jax unavailable ({_IMPORT_ERROR!r})")
-    if not device_draws_canonical():
-        raise RuntimeError(
-            "jax_threefry_partitionable is enabled: device draws would not "
-            "match the canonical counter stream; use "
-            "repro.core.prng.counter_fault_masks instead")
     if gen.samples == 0 or gen.num_nodes == 0:
         return np.zeros((gen.samples, gen.num_nodes), bool)
-    idxs = jnp.arange(gen.samples, dtype=jnp.int32)
-    fn = jax.jit(jax.vmap(lambda idx: _counter_mask(gen, idx)))
-    return np.asarray(fn(idxs))
+    keys = cprng.threefry_fold_in_batch(
+        cprng.threefry_seed(gen.seed),
+        np.arange(start, start + gen.samples, dtype=np.int64))
+    draw = _draw_fn(gen.num_nodes, None)
+    return np.asarray(draw(jnp.asarray(keys),
+                           *_threshold_args(gen.fault_ratio)))
 
 
 def num_devices() -> int:

@@ -49,7 +49,9 @@ def test_chip_smoke_phases_match_numpy_on_one_device(capsys):
     for phase in ("sweep", "dcn", "serve"):
         assert f"{phase}: devices=1 " in out
         assert " == numpy; " in out.split(f"{phase}: ")[-1]
-    assert "masks=host" in out       # jax_threefry_partitionable is on
+    [sweep] = [ln for ln in out.splitlines()
+               if ln.startswith("sweep: devices=1 ")]
+    assert sweep.endswith(" masks=device")     # drawn on the device
 
 
 @pytest.mark.parametrize("chips", [1, 4])

@@ -2,9 +2,10 @@
 bit-for-bit equal (int64 grids) to the NumPy engine.
 
 Covers every registered architecture, awkward TP sizes, empty-snapshot and
-all-faulty edge cases, chunk-boundary invariance, the counter-based
-``jax.random`` mask stream against its NumPy threefry mirror, and (slow
-tier, subprocess) forced 8-device sharding.
+all-faulty edge cases, chunk-boundary invariance, the counter-threefry mask
+stream drawn on the device against its NumPy original (and that stream
+against ``jax.random``'s raw primitives), and (slow tier, subprocess)
+forced 8-device sharding.
 """
 
 import os
@@ -15,8 +16,10 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.core.prng import (counter_fault_masks, ratio_threshold,
-                             threefry_bits, threefry_fold_in, threefry_seed)
+                             threefry2x32, threefry_bits, threefry_fold_in,
+                             threefry_fold_in_batch, threefry_seed)
 from repro.sim import (CounterIIDSnapshots, DEFAULT_ARCHITECTURES,
                        IIDSnapshots, ScenarioSpec, TraceSnapshots,
                        resolve_backend, run_sweep)
@@ -100,19 +103,60 @@ def test_jax_mask_width_clipping():
                             run_sweep(spec, masks=masks, backend="jax"))
 
 
-# ------------------------------------------------- counter-based jax.random
+# ------------------------------------------------ counter masks on device
 
-def test_counter_masks_jax_matches_numpy_mirror():
-    from repro.sim.jax_backend import (MaskGen, counter_masks_device,
-                                       device_draws_canonical)
-    if not device_draws_canonical():
-        pytest.skip("jax_threefry_partitionable: device stream is not the "
-                    "canonical layout (engine falls back to host masks)")
-    for ratio, seed in ((0.07, 0), (0.5, 11), (0.0, 3), (1.0, 5)):
-        gen = MaskGen(samples=13, num_nodes=97, fault_ratio=ratio, seed=seed)
-        dev = counter_masks_device(gen)
-        host = counter_fault_masks(97, ratio, 13, seed)
+@pytest.mark.parametrize("seed", [0, 11, 2**32 + 5, 5_200_000_000])
+def test_threefry2x32_jnp_matches_numpy_on_array_keys(seed):
+    """The jnp cipher with one key per row (host arrays and traced), seeds
+    past 32 bits included, equals the NumPy cipher row by row."""
+    from repro.core.prng import counter_lanes
+    from repro.faults.jax_mirror import threefry2x32_jnp
+    keys = threefry_fold_in_batch(threefry_seed(seed), np.arange(9) + 3)
+    c0, c1 = counter_lanes(101)
+    want = [threefry2x32(k[0], k[1], c0, c1) for k in keys]
+    host = threefry2x32_jnp(keys[:, :1], keys[:, 1:], c0, c1)
+    traced = jax.jit(lambda k: threefry2x32_jnp(k[:, :1], k[:, 1:], c0,
+                                                c1))(keys)
+    for got in (host, traced):
+        assert np.array_equal(np.asarray(got[0]), np.stack([w[0] for w in want]))
+        assert np.array_equal(np.asarray(got[1]), np.stack([w[1] for w in want]))
+
+
+@pytest.mark.parametrize("num_nodes", [97, 64])
+@pytest.mark.parametrize("start", [0, 1_000_003])
+def test_counter_masks_jax_matches_numpy_mirror(num_nodes, start):
+    from repro.sim.jax_backend import MaskGen, counter_masks_device
+    for ratio, seed in ((0.07, 0), (0.5, 11), (0.0, 3), (1.0, 5),
+                        (0.07, 5_200_000_000)):
+        gen = MaskGen(samples=13, num_nodes=num_nodes, fault_ratio=ratio,
+                      seed=seed)
+        dev = counter_masks_device(gen, start=start)
+        host = counter_fault_masks(num_nodes, ratio, 13, seed, start=start)
         assert np.array_equal(dev, host), (ratio, seed)
+
+
+def test_counter_specs_of_one_shape_share_one_executable(tel):
+    """Seed and ratio are arguments of the device programs, not part of
+    their cache keys: a second spec that differs in both compiles
+    nothing."""
+    from repro.sim import jax_backend
+
+    def spec(ratio, seed):
+        return ScenarioSpec(num_nodes=203,
+                            snapshots=CounterIIDSnapshots(ratio, samples=24,
+                                                          seed=seed),
+                            tp_sizes=(16, 32),
+                            architectures=("infinitehbd-k3", "nvl-72"))
+
+    first = run_sweep(spec(0.05, 1), backend="jax")
+    second = run_sweep(spec(0.12, 2**33 + 9), backend="jax")
+    assert obs.summary()["counters"]["sim.jax.jit_cache_miss"] == 1
+    [draw] = [fn for (width, mesh), fn in jax_backend._DRAW_CACHE.items()
+              if width == 203 and mesh is None]
+    [grid] = [fn for key, fn in jax_backend._GRID_CACHE.items()
+              if key[2] == 203 and key[3] is None]
+    assert draw._cache_size() == grid._cache_size() == 1
+    assert not np.array_equal(first.placed_gpus, second.placed_gpus)
 
 
 def test_counter_mirror_matches_jax_random_primitives():
@@ -131,15 +175,28 @@ def test_counter_mirror_matches_jax_random_primitives():
         assert np.array_equal(got, ref), n
 
 
-def test_counter_spec_cross_backend_device_generation():
-    """The jax backend draws counter masks on device (no host matrix) and
+def test_counter_spec_cross_backend_device_generation(tel):
+    """The jax backend draws every block of a counter spec on the device,
+    the short tail block included (no host matrix, no host draw), and
     still matches the NumPy engine bit-for-bit."""
     spec = ScenarioSpec(num_nodes=210,
                         snapshots=CounterIIDSnapshots(0.09, samples=37,
                                                       seed=6),
                         tp_sizes=(16, 32, 48))
-    _assert_grids_equal(run_sweep(spec, backend="numpy"),
-                        run_sweep(spec, backend="jax", chunk_snapshots=10))
+    ref = run_sweep(spec, backend="numpy")
+    for chunk in (1, 10, 1000):
+        obs.reset()
+        got = run_sweep(spec, backend="jax", chunk_snapshots=chunk)
+        _assert_grids_equal(ref, got)
+        rows = [min(chunk, 37 - lo) for lo in range(0, 37, chunk)]
+        assert [r.attrs["rows"] for r in tel.spans
+                if r.name == "sim.jax.eval_block"] == rows
+        assert [r.attrs for r in tel.spans
+                if r.name == "prng.device_masks"] == \
+            [{"samples": n, "nodes": 210} for n in rows]
+        counters = obs.summary()["counters"]
+        assert counters["prng.device_masks_drawn"] == 37
+        assert "prng.masks_generated" not in counters
 
 
 def test_counter_masks_row_depends_only_on_seed_and_index():

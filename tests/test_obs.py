@@ -48,18 +48,6 @@ def _spec(samples, num_nodes=144, ratio=0.07, seed=3):
 
 
 @pytest.fixture
-def tel():
-    """Enabled, empty global telemetry; restores prior state afterwards."""
-    prev = obs.enabled()
-    obs.reset()
-    obs.enable()
-    yield obs.TELEMETRY
-    obs.reset()
-    if not prev:
-        obs.disable()
-
-
-@pytest.fixture
 def disabled():
     prev = obs.enabled()
     obs.disable()
@@ -274,7 +262,7 @@ def _within(inner, outer) -> bool:
 
 @pytest.mark.skipif(not jax_backend.HAVE_JAX, reason="jax unavailable")
 def test_sweep_host_layer_spans(tel):
-    # 64 nodes, two blocks of 32 host-drawn snapshots
+    # 64 nodes, two blocks of 32 snapshots drawn on the device
     result = run_sweep(_spec(64, num_nodes=64), backend="jax",
                        chunk_snapshots=32)
     waste_table(result)
@@ -283,17 +271,21 @@ def test_sweep_host_layer_spans(tel):
     assert models.attrs == {"models": len(ARCHES)}
     blocks = _named(tel, "sim.jax.eval_block")
     assert [b.attrs["rows"] for b in blocks] == [32, 32]
-    # one evaluator per block on the streamed path
-    assert [s.attrs for s in _named(tel, "sim.jax.setup")] == \
-        [{"rows": 32}] * 2
+    # one evaluator for the whole spec
+    assert [s.attrs for s in _named(tel, "sim.jax.setup")] == [{"rows": 64}]
     puts, fetches = _named(tel, "sim.jax.put"), _named(tel, "sim.jax.fetch")
-    assert len(puts) == len(fetches) == 2
-    for block, put, fetch in zip(blocks, puts, fetches):
-        assert put.attrs == {"rows": 32, "bytes": 32 * 64}
+    draws = _named(tel, "prng.device_masks")
+    assert len(puts) == len(draws) == len(fetches) == 2
+    assert not _named(tel, "prng.counter_fault_masks")
+    for block, put, draw, fetch in zip(blocks, puts, draws, fetches):
+        # the copy is each row's two-word threefry key, not its mask
+        assert put.attrs == {"rows": 32, "bytes": 32 * 8}
+        assert draw.attrs == {"samples": 32, "nodes": 64}
         assert fetch.attrs == {"rows": 32}
-        for inner in (put, fetch):
+        for inner in (put, draw, fetch):
             assert _within(inner, block) and inner.depth == block.depth + 1
-        assert put.start_ns + put.dur_ns <= fetch.start_ns
+        assert put.start_ns + put.dur_ns <= draw.start_ns
+        assert draw.start_ns + draw.dur_ns <= fetch.start_ns
     for name in ("sim.tables.waste_table", "sim.tables.max_job_table"):
         [table] = _named(tel, name)
         assert table.attrs == {"rows": 64}
@@ -367,13 +359,17 @@ def test_device_programs_carry_stable_names():
     import jax
     from repro.dcn import jax_backend as dcn_jax
     models = _spec(4, num_nodes=64).models()
-    sweep = jax_backend._grid_fn(models, [16], None, None, 64).lower(
+    sweep = jax_backend._grid_fn(models, [16], None, 64).lower(
         jax.ShapeDtypeStruct((4, 64), bool)).compile().as_text()
     assert "HloModule jit_eval_mask" in sweep
     for m in models:
         # each architecture kernel's ops sit in a scope of its name,
         # which vmap wraps: op_name="jit(eval_mask)/vmap(nvl-72)/..."
         assert f"/vmap({m.name})/" in sweep, m.name
+    draw = jax_backend._draw_fn(64, None).lower(
+        jax.ShapeDtypeStruct((4, 2), np.uint32), np.uint32(0),
+        np.bool_(False)).compile().as_text()
+    assert "HloModule jit_draw_counter_masks" in draw
     spec = DcnSpec(num_nodes=256, tp_sizes=(32,), agg_domain=64)
     dcn = dcn_jax._grid_fn(spec.config, (32,), (spec.job_gpus(32),),
                            None).lower(

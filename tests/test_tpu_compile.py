@@ -59,7 +59,7 @@ def _sweep_program(topo, arches, nodes: int, chips: int):
     models = [make_model(a, nodes, 4) for a in arches]
     sharding = _sharding(topo, chips)
     mesh = None if chips == 1 else sharding.mesh
-    fn = sim_jax._grid_fn(models, [16, 32, 64], mesh, None, nodes)
+    fn = sim_jax._grid_fn(models, [16, 32, 64], mesh, nodes)
     arg = jax.ShapeDtypeStruct((ROWS, nodes), bool, sharding=sharding)
     return fn.lower(arg).compile()
 
@@ -84,6 +84,21 @@ def test_smoke_sweep_block_fits_one_chip(topo):
             + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes == ROWS * 32_768
     assert used < V5E_HBM_BYTES // 4, used
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_mask_draw_compiles(topo, chips):
+    """The counter-mask draw of one sweep block at 131,072 GPUs: the
+    block's keys in, its bool masks out, split over the chips."""
+    sharding = _sharding(topo, chips)
+    mesh = None if chips == 1 else sharding.mesh
+    fn = sim_jax._draw_fn(32_768, mesh)
+    keys = jax.ShapeDtypeStruct((ROWS, 2), jnp.uint32, sharding=sharding)
+    one = _sharding(topo, 1) if chips == 1 else NamedSharding(mesh, P())
+    thresh = jax.ShapeDtypeStruct((), jnp.uint32, sharding=one)
+    full = jax.ShapeDtypeStruct((), jnp.bool_, sharding=one)
+    mem = fn.lower(keys, thresh, full).compile().memory_analysis()
+    assert mem.output_size_in_bytes == ROWS * 32_768 // chips
 
 
 def test_dcn_program_compiles(topo):
