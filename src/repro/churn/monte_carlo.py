@@ -9,15 +9,17 @@ one ``evaluate_masks`` call -- on the JAX backend that means thousands of
 348-day traces stream through the device-sharded `vmap`/`jit` grid in
 seconds, bit-for-bit equal to the scalar event-by-event replay
 (``benchmarks/churn.py`` gates the >= 10x throughput claim).  For
-ensembles too large to concatenate, ``engine="streamed"`` re-chunks the
-realizations through ``evaluate_mask_stream`` in bounded memory with the
-same bit-for-bit grids (``tests/test_stream.py``).
+horizons or ensembles too large to concatenate, ``engine="streamed"``
+builds each realization's masks block by block and re-chunks them through
+``evaluate_mask_stream`` in bounded memory with the same bit-for-bit grids
+(``tests/test_stream.py``).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
+from typing import (Callable, Dict, Iterator, List, Optional, Sequence,
+                    Tuple, Union)
 
 import numpy as np
 
@@ -91,21 +93,54 @@ class ChurnEnsemble:
         """Per (architecture, TP): waste/goodput stats across realizations."""
         if not self.timelines:
             return []
-        waste = self.integrated_waste()
-        share = self.placed_share()
-        rows = []
-        tl0 = self.timelines[0]
-        for ai, name in enumerate(tl0.names):
-            for ti, tp in enumerate(tl0.tp_sizes):
-                w = waste[:, ai, ti]
-                rows.append({
-                    "architecture": name, "tp_size": int(tp),
-                    "traces": self.num_traces,
-                    "mean_waste": float(w.mean()),
-                    "p99_waste": float(np.percentile(w, 99)),
-                    "mean_placed_share": float(share[:, ai, ti].mean()),
-                })
+        with obs.span("churn.tables", realizations=self.num_traces):
+            waste = self.integrated_waste()
+            share = self.placed_share()
+            rows = []
+            tl0 = self.timelines[0]
+            for ai, name in enumerate(tl0.names):
+                for ti, tp in enumerate(tl0.tp_sizes):
+                    w = waste[:, ai, ti]
+                    rows.append({
+                        "architecture": name, "tp_size": int(tp),
+                        "traces": self.num_traces,
+                        "mean_waste": float(w.mean()),
+                        "p99_waste": float(np.percentile(w, 99)),
+                        "mean_placed_share": float(share[:, ai, ti].mean()),
+                    })
         return rows
+
+
+def _realizations(spec: ChurnSpec,
+                  traces: Union[int, Sequence[FaultTrace]]
+                  ) -> List[Tuple[FaultTrace, np.ndarray]]:
+    """``(trace, interval edges)`` of every realization, each made under a
+    ``churn.trace`` span (generation and 4-GPU split when ``traces`` is a
+    count, then the interval edges)."""
+    count = traces if isinstance(traces, int) else len(traces)
+    out = []
+    for r in range(count):
+        with obs.span("churn.trace", realization=r) as sp:
+            tr = spec.trace(r) if isinstance(traces, int) else traces[r]
+            edges = tr.interval_edges()
+            sp.set(events=len(tr.events), intervals=len(edges))
+        obs.count("churn.fault_events", len(tr.events))
+        obs.count("churn.intervals", len(edges))
+        out.append((tr, edges))
+    return out
+
+
+def _mask_blocks(realizations: Sequence[Tuple[FaultTrace, np.ndarray]],
+                 rows: int) -> Iterator[np.ndarray]:
+    """Every realization's interval masks in turn, ``rows`` intervals per
+    block, each block built under a ``churn.masks`` span."""
+    for tr, edges in realizations:
+        blocks = tr.fault_mask_blocks(edges, rows)
+        for lo in range(0, len(edges), rows):
+            with obs.span("churn.masks", rows=min(rows, len(edges) - lo),
+                          nodes=tr.num_nodes):
+                masks = next(blocks)
+            yield masks
 
 
 def monte_carlo_replay(spec: ChurnSpec,
@@ -120,10 +155,12 @@ def monte_carlo_replay(spec: ChurnSpec,
     pre-generated sequence of :class:`FaultTrace` (the benchmarks pass one
     so engine timing excludes trace generation).  ``engine="batched"``
     evaluates ALL realizations' interval masks in a single scenario-engine
-    pass; ``engine="streamed"`` produces bit-identical timelines but feeds
-    the masks through ``evaluate_mask_stream`` one realization at a time
-    (re-chunked across realization boundaries), bounding peak memory at
-    ~one evaluation block for arbitrarily large ensembles;
+    pass; ``engine="streamed"`` produces bit-identical timelines but builds
+    each realization's masks ``chunk_snapshots`` intervals at a time
+    (``FaultTrace.fault_mask_blocks``) and feeds them through
+    ``evaluate_mask_stream`` (re-chunked across realization boundaries),
+    bounding peak host mask memory at about one mask block plus one
+    evaluation block whatever the horizon or the ensemble size;
     ``engine="scalar"`` loops the event-by-event reference replay.
 
     ``progress`` (``engine="streamed"`` only) is forwarded to
@@ -131,52 +168,48 @@ def monte_carlo_replay(spec: ChurnSpec,
     evaluated block; the default publishes ``sim.stream.*`` telemetry
     gauges (blocks done, snapshots/sec, ETA).
     """
-    if isinstance(traces, int):
-        realizations = [spec.trace(r) for r in range(traces)]
-    else:
-        realizations = list(traces)
-
+    if engine not in ("batched", "streamed", "scalar"):
+        raise ValueError(f"unknown engine {engine!r} (batched|streamed|scalar)")
+    realizations = _realizations(spec, traces)
     if engine == "scalar":
         tls = [replay_trace(tr, tp_sizes=spec.tp_sizes,
                             architectures=spec.architectures,
                             gpus_per_node=spec.gpus_per_node, engine="scalar")
-               for tr in realizations]
+               for tr, _ in realizations]
         return ChurnEnsemble(spec, tls, "scalar")
-    if engine not in ("batched", "streamed"):
-        raise ValueError(f"unknown engine {engine!r} (batched|streamed|scalar)")
 
     models = spec.models()
     names = [m.name for m in models]
     tps = np.asarray(spec.tp_sizes, dtype=np.int64)
     with obs.span("churn.monte_carlo_replay", engine=engine,
                   realizations=len(realizations)):
-        edges_list = [tr.interval_edges() for tr in realizations]
+        intervals = int(sum(len(e) for _, e in realizations))
         if engine == "streamed":
-            chunks = (tr.fault_masks(e)
-                      for tr, e in zip(realizations, edges_list))
+            chunk_snapshots = max(1, chunk_snapshots)
             total, faulty, placed, chosen = evaluate_mask_stream(
-                models, spec.tp_sizes, chunks,
-                int(sum(len(e) for e in edges_list)),
+                models, spec.tp_sizes,
+                _mask_blocks(realizations, chunk_snapshots), intervals,
                 chunk_snapshots=chunk_snapshots, backend=backend,
                 progress=progress)
         else:
             if realizations:
-                masks = np.concatenate([tr.fault_masks(e) for tr, e
-                                        in zip(realizations, edges_list)])
+                masks = np.concatenate([tr.fault_masks(e)
+                                        for tr, e in realizations])
             else:
                 masks = np.zeros((0, spec.num_nodes), dtype=bool)
             total, faulty, placed, chosen = evaluate_masks(
                 models, spec.tp_sizes, masks,
                 chunk_snapshots=chunk_snapshots, backend=backend)
 
-    tls = []
-    lo = 0
-    for tr, edges in zip(realizations, edges_list):
-        hi = lo + len(edges)
-        tls.append(ChurnTimeline(tr.horizon_h, edges, list(names), tps,
-                                 total.copy(), faulty[:, lo:hi].copy(),
-                                 placed[:, lo:hi].copy(), backend=chosen))
-        lo = hi
+    with obs.span("churn.tables", realizations=len(realizations)):
+        tls = []
+        lo = 0
+        for tr, edges in realizations:
+            hi = lo + len(edges)
+            tls.append(ChurnTimeline(tr.horizon_h, edges, list(names), tps,
+                                     total.copy(), faulty[:, lo:hi].copy(),
+                                     placed[:, lo:hi].copy(), backend=chosen))
+            lo = hi
     return ChurnEnsemble(spec, tls, chosen)
 
 

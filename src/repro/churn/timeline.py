@@ -21,6 +21,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import obs
 
 @dataclasses.dataclass(frozen=True)
 class ReconfigRecord:
@@ -84,6 +85,12 @@ class ChurnTimeline:
         return int(np.nonzero(self.tp_sizes == tp)[0][0])
 
     # -------------------------------------------------- time integration
+    #
+    # Every time integral contracts the interval axis of a float64 copy of
+    # the series with one ``np.einsum("abt,b->at", ...)`` against the
+    # float64 durations (or their shares of the horizon): that call fixes
+    # the summation order, so a reduction rebuilt the same way from the
+    # same grids is bit-for-bit equal.
 
     def time_mean(self, series: np.ndarray) -> np.ndarray:
         """Duration-weighted mean of an ``(A, B, T)`` series over intervals."""
@@ -144,20 +151,21 @@ class ChurnTimeline:
 
 def integrated_waste_table(timeline: ChurnTimeline) -> List[Dict]:
     """Per (architecture, TP): time-integrated waste/goodput over the trace."""
-    waste = timeline.integrated_waste_ratio()
-    good = timeline.goodput_gpu_hours()
-    wasted = timeline.wasted_gpu_hours()
-    share = timeline.placed_share()
-    rows = []
-    for ai, name in enumerate(timeline.names):
-        for ti, tp in enumerate(timeline.tp_sizes):
-            rows.append({
-                "architecture": name, "tp_size": int(tp),
-                "time_mean_waste": float(waste[ai, ti]),
-                "wasted_gpu_h": float(wasted[ai, ti]),
-                "goodput_gpu_h": float(good[ai, ti]),
-                "placed_share": float(share[ai, ti]),
-            })
+    with obs.span("churn.tables", intervals=timeline.num_intervals):
+        waste = timeline.integrated_waste_ratio()
+        good = timeline.goodput_gpu_hours()
+        wasted = timeline.wasted_gpu_hours()
+        share = timeline.placed_share()
+        rows = []
+        for ai, name in enumerate(timeline.names):
+            for ti, tp in enumerate(timeline.tp_sizes):
+                rows.append({
+                    "architecture": name, "tp_size": int(tp),
+                    "time_mean_waste": float(waste[ai, ti]),
+                    "wasted_gpu_h": float(wasted[ai, ti]),
+                    "goodput_gpu_h": float(good[ai, ti]),
+                    "placed_share": float(share[ai, ti]),
+                })
     return rows
 
 

@@ -56,33 +56,58 @@ class FaultTrace:
         t < end`` comparisons, evaluated with searchsorted on the sorted
         sample times), so the batched scenario engine sees bit-identical
         snapshots to the scalar path -- in one vectorized sweep instead of
-        O(samples * events) Python.
+        O(samples * events) Python.  The one-block case of
+        :meth:`fault_mask_blocks`.
+        """
+        for masks in self.fault_mask_blocks(ts, max(1, len(ts))):
+            return masks
+        return np.zeros((0, self.num_nodes), dtype=bool)
+
+    def fault_mask_blocks(self, ts: Sequence[float],
+                          block: int) -> Iterator[np.ndarray]:
+        """:meth:`fault_masks` of ``ts``, ``block`` rows at a time.
+
+        Yields ``(rows, num_nodes)`` bool matrices whose concatenation is
+        ``fault_masks(ts)`` bit for bit, so a long horizon never holds more
+        than one block.  Each block starts from every node's active-event
+        count at its first sample time, carried from the blocks before it:
+        the events whose start (end) index falls inside a block are a slice
+        of the events sorted by that index.
         """
         ts = np.asarray(ts, dtype=np.float64)
         if len(ts) > 1 and np.any(np.diff(ts) < 0):
             raise ValueError("fault_masks requires ascending sample times "
                              "(searchsorted semantics)")
-        masks = np.zeros((len(ts), self.num_nodes), dtype=bool)
-        if not self.events or not len(ts):
-            return masks
-        starts = np.array([e.start_h for e in self.events])
-        ends = np.array([e.end_h for e in self.events])
-        nodes = np.array([e.node for e in self.events])
+        if block < 1:
+            raise ValueError(f"block must be positive, got {block}")
+        starts = np.array([e.start_h for e in self.events], dtype=np.float64)
+        ends = np.array([e.end_h for e in self.events], dtype=np.float64)
+        nodes = np.array([e.node for e in self.events], dtype=np.int64)
         # event active at ts[i] iff i >= searchsorted(start) and i < searchsorted(end)
         i0 = np.searchsorted(ts, starts, side="left")
         i1 = np.searchsorted(ts, ends, side="left")
-        # int16 + in-place cumsum keeps the peak footprint at ~2x the bool
-        # mask even for 100k-node x multi-thousand-snapshot grids (the count
-        # is concurrently-active events per node, far below the int16 range);
-        # the (node, time) layout makes the cumsum contiguous (~4x faster
-        # than accumulating down the snapshot axis)
-        delta = np.zeros((self.num_nodes, len(ts) + 1), dtype=np.int16)
-        np.add.at(delta, (nodes, i0), 1)
-        np.add.at(delta, (nodes, i1), -1)
-        np.cumsum(delta[:, :-1], axis=1, out=delta[:, :-1])
-        out = np.empty((len(ts), self.num_nodes), dtype=bool)
-        np.greater(delta[:, :-1].T, 0, out=out)    # one C-ordered allocation
-        return out
+        by0, by1 = np.argsort(i0, kind="stable"), np.argsort(i1, kind="stable")
+        sides = ((i0[by0], nodes[by0], 1), (i1[by1], nodes[by1], -1))
+        carry = np.zeros(self.num_nodes, dtype=np.int16)
+        for lo in range(0, len(ts), block):
+            hi = min(lo + block, len(ts))
+            # int16 + in-place cumsum keeps the peak footprint at ~2x the
+            # bool mask even for 100k-node x multi-thousand-snapshot grids
+            # (the count is concurrently-active events per node, far below
+            # the int16 range); the (node, time) layout makes the cumsum
+            # contiguous (~4x faster than accumulating down the snapshot
+            # axis)
+            delta = np.zeros((self.num_nodes, hi - lo), dtype=np.int16)
+            delta[:, 0] = carry
+            for index, who, step in sides:
+                a, b = np.searchsorted(index, (lo, hi), side="left")
+                np.add.at(delta, (who[a:b], index[a:b] - lo), step)
+            np.cumsum(delta, axis=1, out=delta)
+            carry = delta[:, -1].copy()
+            out = np.empty((hi - lo, self.num_nodes), dtype=bool)
+            np.greater(delta.T, 0, out=out)    # one C-ordered allocation
+            del delta
+            yield out
 
     def interval_edges(self) -> np.ndarray:
         """Left edges of the piecewise-constant fault-set intervals.

@@ -31,14 +31,20 @@ Spans of the path from a spec to its tables, outermost first:
     models), ``sim.run_sweep``, ``prng.counter_fault_masks`` (host mask
     draw), ``sim.stream.block`` / ``sim.evaluate_masks``,
     ``sim.jax.setup`` (evaluator and totals, once per ``sweep_grids``
-    call, so once per block on the streamed path), ``sim.jax.eval_block``
+    call or mask stream), ``sim.jax.eval_block``
     holding ``sim.jax.put`` (host to device) and ``sim.jax.fetch`` (device
     to host, int64 unpacking), then ``sim.tables.waste_table`` /
     ``sim.tables.max_job_table``;
   * DCN (``repro.dcn``): ``dcn.run_dcn_sweep``, ``dcn.evaluate_placements``
     per variant and TP (the orchestrated one holding ``dcn.jax.put`` /
     ``dcn.jax.fetch`` per block), ``dcn.pair_counts`` per variant and TP,
-    then ``dcn.tables.traffic_tables``.
+    then ``dcn.tables.traffic_tables``;
+  * churn (``repro.churn``): ``churn.trace`` per realization (generation,
+    4-GPU split and interval edges; counters ``churn.fault_events`` and
+    ``churn.intervals``), ``churn.monte_carlo_replay`` holding the
+    streamed evaluation with one ``churn.masks`` per block of interval
+    masks, then ``churn.tables`` around the timeline slicing and each
+    ``integrated_waste_table`` / ``ChurnEnsemble.summary_table``.
 """
 
 # import the .export submodule eagerly: a first lazy import (inside

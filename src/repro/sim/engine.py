@@ -172,14 +172,16 @@ def evaluate_mask_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
 
     ``chunks`` is any iterable of ``(rows_i, nodes)`` bool matrices whose
     rows concatenate to ``total_snapshots`` snapshots.  Incoming chunks are
-    re-chunked into ~``chunk_snapshots`` evaluation blocks (chunk
-    boundaries in the source need not align with evaluation boundaries), so
-    the grids are bit-for-bit equal to one :func:`evaluate_masks` call on
-    the full concatenation while peak mask memory stays at about one block
-    plus the largest single source chunk -- a million-snapshot x 10k-node
-    stream never exists as a 10 GB host matrix.  On the JAX backend each
-    block flows through the same jit-cached, donated device buffers as the
-    batched path (``repro.sim.jax_backend.GridEvaluator``).
+    re-chunked into evaluation blocks of exactly ``chunk_snapshots`` rows
+    (chunk boundaries in the source need not align with evaluation
+    boundaries; only the stream's last block may be shorter), so the grids
+    are bit-for-bit equal to one :func:`evaluate_masks` call on the full
+    concatenation while peak mask memory stays at about one block plus the
+    largest single source chunk -- a million-snapshot x 10k-node stream
+    never exists as a 10 GB host matrix.  On the JAX backend every block
+    flows through one ``repro.sim.jax_backend.GridEvaluator`` sized to the
+    block, which pads the short last block up to it: however the stream's
+    length falls, it runs one compiled shape, with donated device buffers.
 
     ``progress`` is called once per evaluated block with a
     :class:`repro.obs.Progress` (blocks done, snapshots/sec, ETA); the
@@ -194,23 +196,32 @@ def evaluate_mask_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
     faulty = np.zeros((a_count, total_snapshots, t_count), dtype=np.int64)
     placed = np.zeros((a_count, total_snapshots, t_count), dtype=np.int64)
     chunk_snapshots = max(1, chunk_snapshots)
-    state = {"lo": 0}
+    state = {"lo": 0, "evaluator": None}
     pending: List[np.ndarray] = []
-    pending_rows = 0
     tracker = StreamProgress(total_snapshots, progress, prefix="sim.stream")
 
-    def flush() -> None:
-        if not pending:
-            return
-        block = pending[0] if len(pending) == 1 else np.concatenate(pending)
-        del pending[:]
-        lo = state["lo"]
-        with obs.span("sim.stream.block", rows=block.shape[0], offset=lo,
-                      backend=chosen):
+    def evaluate(block: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+        if chosen != "jax":
             t, f, p, _ = evaluate_masks(models, tp_sizes, block,
                                         chunk_snapshots=chunk_snapshots,
                                         backend=chosen)
-        total[:] = t
+            total[:] = t
+            return f, p
+        if state["evaluator"] is None:
+            from . import jax_backend
+            with obs.span("sim.jax.setup", rows=total_snapshots):
+                state["evaluator"] = jax_backend.GridEvaluator(
+                    models, tp_sizes, block.shape[1],
+                    rows=min(chunk_snapshots, total_snapshots))
+                total[:] = state["evaluator"].totals()
+        obs.count("sim.snapshots_evaluated", block.shape[0])
+        return state["evaluator"].eval_block(block)
+
+    def flush(block: np.ndarray) -> None:
+        lo = state["lo"]
+        with obs.span("sim.stream.block", rows=block.shape[0], offset=lo,
+                      backend=chosen):
+            f, p = evaluate(block)
         faulty[:, lo:lo + block.shape[0]] = f
         placed[:, lo:lo + block.shape[0]] = p
         state["lo"] = lo + block.shape[0]
@@ -223,11 +234,17 @@ def evaluate_mask_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
             if not chunk.shape[0]:
                 continue
             pending.append(chunk)
-            pending_rows += chunk.shape[0]
-            if pending_rows >= chunk_snapshots:
-                flush()
-                pending_rows = 0
-        flush()
+            if sum(c.shape[0] for c in pending) < chunk_snapshots:
+                continue
+            rows = pending[0] if len(pending) == 1 else np.concatenate(pending)
+            del pending[:]
+            full = rows.shape[0] - rows.shape[0] % chunk_snapshots
+            for lo in range(0, full, chunk_snapshots):
+                flush(rows[lo:lo + chunk_snapshots])
+            if full < rows.shape[0]:        # a copy frees the source
+                pending.append(rows[full:].copy())
+        if pending:
+            flush(pending[0] if len(pending) == 1 else np.concatenate(pending))
     if state["lo"] != total_snapshots:
         raise ValueError(f"mask stream yielded {state['lo']} snapshots, "
                          f"expected {total_snapshots}")

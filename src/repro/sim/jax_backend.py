@@ -365,11 +365,12 @@ class GridEvaluator:
     is a loop over :meth:`eval_block`; ``repro.sim.engine``'s
     ``evaluate_mask_stream`` drives one evaluator across an entire mask
     stream (million-snapshot Monte-Carlo) without ever materializing the
-    full matrix on host or device.
+    full matrix on host or device.  ``rows`` is the caller's block size:
+    shorter blocks are padded up to it.
     """
 
     def __init__(self, models: Sequence[HBDModel], tps: Sequence[int],
-                 width: int, gen: Optional[MaskGen] = None):
+                 width: int, gen: Optional[MaskGen] = None, rows: int = 1):
         require(models)
         self.models = list(models)
         self.tps = [int(t) for t in tps]
@@ -377,6 +378,9 @@ class GridEvaluator:
         self.gen = gen
         self.mesh = snapshot_mesh(_SNAP_AXIS)
         self.ndev = 1 if self.mesh is None else self.mesh.devices.size
+        # the block shape every shorter block is padded to: a stream whose
+        # last block is short runs the executable its full blocks compiled
+        self.rows = -(-max(1, rows) // self.ndev) * self.ndev
         self.sharding = (None if self.mesh is None
                          else NamedSharding(self.mesh, P(_SNAP_AXIS)))
         self.fn = _grid_fn(self.models, self.tps, self.mesh, width)
@@ -396,8 +400,10 @@ class GridEvaluator:
         ``block`` is a ``(rows, width)`` bool mask matrix -- or, when the
         evaluator was built with ``gen``, a ``(rows,)`` integer vector of
         counter-stream snapshot indices, whose masks are drawn on the
-        device.  Rows are padded on the tail to a device-count multiple and
-        the pad rows discarded.
+        device.  Rows are padded on the tail to the evaluator's ``rows``
+        (a block longer than that to a device-count multiple) and the pad
+        rows discarded, so every block of up to ``rows`` runs one compiled
+        shape.
 
         Spans: ``sim.jax.eval_block`` around the whole block, inside it
         ``sim.jax.put`` (padding and the copy to the device: the mask
@@ -411,7 +417,7 @@ class GridEvaluator:
         rows = block.shape[0]
         with obs.span("sim.jax.eval_block", rows=rows, devices=self.ndev):
             with obs.span("sim.jax.put", rows=rows) as sp:
-                padded = -(-rows // self.ndev) * self.ndev
+                padded = max(self.rows, -(-rows // self.ndev) * self.ndev)
                 if padded != rows:             # pad the tail chunk only
                     block = np.concatenate(
                         [block, np.zeros((padded - rows,) + block.shape[1:],
@@ -467,13 +473,13 @@ def sweep_grids(models: Sequence[HBDModel], tps: Sequence[int], *,
     if snaps == 0:  # NumPy engine's zero-snapshot grid keeps totals at zero
         return total, faulty, placed
 
-    with obs.span("sim.jax.setup", rows=snaps):
-        ev = GridEvaluator(models, tps, width, gen=gen)
-        total[:] = ev.totals()
     chunk = max(1, chunk_snapshots)
-    chunk = -(-chunk // ev.ndev) * ev.ndev     # multiple of the device count
-    for lo in range(0, snaps, chunk):
-        hi = min(lo + chunk, snaps)
+    with obs.span("sim.jax.setup", rows=snaps):
+        ev = GridEvaluator(models, tps, width, gen=gen,
+                           rows=min(chunk, snaps))
+        total[:] = ev.totals()
+    for lo in range(0, snaps, ev.rows):
+        hi = min(lo + ev.rows, snaps)
         block = (masks[lo:hi] if masks is not None
                  else np.arange(lo, hi, dtype=np.int64))
         f, p = ev.eval_block(block)

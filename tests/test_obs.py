@@ -26,7 +26,7 @@ import pytest
 
 from repro import obs
 from repro.churn import ChurnJob, ChurnSpec, control_plane_replay, \
-    monte_carlo_replay
+    integrated_waste_table, monte_carlo_replay
 from repro.core.control_plane import ClusterManager
 from repro.obs import NULL_SPAN, Progress
 from repro.sim import DcnSpec, jax_backend, run_dcn_sweep, traffic_tables
@@ -389,8 +389,10 @@ def test_stream_progress_custom_callback():
     evaluate_mask_stream(models, spec.tp_sizes, chunks, 57,
                          chunk_snapshots=16, backend="numpy",
                          progress=seen.append)
-    assert len(seen) == 3 and all(isinstance(p, Progress) for p in seen)
+    # exact blocks of 16 rows: 16, 16, 16 and the last 9
+    assert len(seen) == 4 and all(isinstance(p, Progress) for p in seen)
     assert [p.blocks_done for p in seen] == list(range(1, len(seen) + 1))
+    assert [p.units_done for p in seen] == [16, 32, 48, 57]
     assert seen[-1].units_done == seen[-1].total_units == 57
     assert seen[-1].fraction == 1.0
     done = [p.units_done for p in seen]
@@ -448,6 +450,37 @@ def test_churn_reconfig_spans_carry_latency_and_gpu_delta(tel):
             assert sp.attrs["placed_gpus"] == rec.placed_gpus
             assert sp.attrs["gpu_delta"] == rec.placed_gpus - prev_gpus
             prev_gpus = rec.placed_gpus
+
+
+# ------------------------------------------------- churn: trace, masks, tables
+
+
+def test_churn_streamed_spans_and_counters(tel):
+    """One ``churn.trace`` per realization (events, intervals), one
+    ``churn.masks`` per block (rows, nodes) covering every interval, and
+    ``churn.tables`` around the timeline slicing and each table."""
+    spec = ChurnSpec(trace_nodes=24, horizon_h=10 * 24.0, tp_sizes=(16,),
+                     architectures=ARCHES, seed=3)
+    ens = monte_carlo_replay(spec, 2, engine="streamed", backend="numpy",
+                             chunk_snapshots=16)
+    intervals = [tl.num_intervals for tl in ens.timelines]
+    traces = [r for r in tel.spans if r.name == "churn.trace"]
+    assert [sp.attrs["realization"] for sp in traces] == [0, 1]
+    assert [sp.attrs["intervals"] for sp in traces] == intervals
+    events = [len(spec.trace(r).events) for r in range(2)]
+    assert [sp.attrs["events"] for sp in traces] == events
+    masks = [r for r in tel.spans if r.name == "churn.masks"]
+    assert len(masks) == sum(-(-n // 16) for n in intervals)
+    assert sum(sp.attrs["rows"] for sp in masks) == sum(intervals)
+    assert all(sp.attrs["nodes"] == spec.num_nodes for sp in masks)
+    counters = obs.summary()["counters"]
+    assert counters["churn.fault_events"] == sum(events)
+    assert counters["churn.intervals"] == sum(intervals)
+    n_tables = len([r for r in tel.spans if r.name == "churn.tables"])
+    integrated_waste_table(ens.timelines[0])
+    ens.summary_table()
+    assert len([r for r in tel.spans if r.name == "churn.tables"]) \
+        == n_tables + 2 == 3
 
 
 # ------------------------------------------------- stragglers

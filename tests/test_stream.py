@@ -9,6 +9,7 @@ sizes of 1 and larger than the whole stream.
 import os
 import subprocess
 import sys
+import tracemalloc
 
 import numpy as np
 import pytest
@@ -93,7 +94,10 @@ def test_counter_mask_start_offset_is_the_stream():
     assert np.array_equal(np.concatenate(parts), full)
 
 
-@pytest.mark.parametrize("chunk_snapshots", [1, 37, 100_000])
+# the three realizations have 226, 263 and 235 intervals: blocks smaller
+# than one realization (37, 100), one realization long (226) and longer
+# than one (300) all straddle realization boundaries
+@pytest.mark.parametrize("chunk_snapshots", [1, 37, 100, 226, 300, 100_000])
 def test_monte_carlo_streamed_matches_batched(chunk_snapshots):
     spec = ChurnSpec(trace_nodes=60, horizon_h=24.0 * 30, tp_sizes=(16, 32),
                      architectures=ARCHES, seed=7)
@@ -107,6 +111,29 @@ def test_monte_carlo_streamed_matches_batched(chunk_snapshots):
         assert np.array_equal(tg.faulty_gpus, tr.faulty_gpus)
         assert np.array_equal(tg.placed_gpus, tr.placed_gpus)
     assert np.array_equal(got.integrated_waste(), ref.integrated_waste())
+
+
+def test_monte_carlo_streamed_peak_memory_is_a_block():
+    """A long realization streams in a small multiple of one mask block:
+    the whole (intervals, nodes) matrix and its int16 counts never exist."""
+    spec = ChurnSpec(trace_nodes=1024, horizon_h=24.0 * 120,
+                     tp_sizes=(32,), architectures=("big-switch",), seed=11)
+    traces = [spec.trace(0)]
+    intervals = len(traces[0].interval_edges())
+    block = 64
+    whole = intervals * spec.num_nodes * 3          # bool masks + int16
+    one_block = block * spec.num_nodes * 3
+    tracemalloc.start()
+    try:
+        got = monte_carlo_replay(spec, traces, engine="streamed",
+                                 backend="numpy", chunk_snapshots=block)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert got.timelines[0].num_intervals == intervals > 40 * block
+    # the grids and timelines themselves take (intervals x 8 B) per array
+    assert peak < 8 * one_block + 64 * intervals
+    assert peak < whole / 10
 
 
 def test_monte_carlo_streamed_empty():
@@ -133,6 +160,41 @@ def test_stream_jax_backend_matches_numpy():
     assert got[3] == "jax"
     for g, r in zip(got[:3], ref[:3]):
         assert np.array_equal(g, r)
+
+
+def test_stream_jax_backend_runs_one_compiled_shape():
+    """Streams whose last blocks differ in length pad them up to the block:
+    after one stream has compiled the block, no other stream of that block
+    size compiles anything, whatever its length."""
+    pytest.importorskip("jax")
+    from jax import monitoring
+    events = ("/jax/core/compile/backend_compile_duration",
+              "/jax/compilation_cache/cache_retrieval_time_sec")
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event in events:
+            compiles.append(event)
+
+    spec = _spec(61, num_nodes=144, seed=5)
+    models = spec.models()
+    masks = spec.snapshots.masks(spec.num_nodes)
+    evaluate_mask_stream(models, spec.tp_sizes, [masks[:16]], 16,
+                         chunk_snapshots=8, backend="jax")
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        # tails of 5 and 3 rows, source chunks straddling the blocks
+        for total, sizes in ((61, [13, 30, 18]), (43, [10, 1, 32])):
+            ref = evaluate_masks(models, spec.tp_sizes, masks[:total],
+                                 backend="numpy")
+            got = evaluate_mask_stream(models, spec.tp_sizes,
+                                       _split(masks[:total], sizes), total,
+                                       chunk_snapshots=8, backend="jax")
+            for g, r in zip(got[:3], ref[:3]):
+                assert np.array_equal(g, r)
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
 
 
 @pytest.mark.slow

@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from repro.core.trace import (BAYES_SPLIT_P, FAULT_RATIO_4GPU,
-                              MEAN_FAULT_RATIO_8GPU, generate_trace,
-                              to_4gpu_trace)
+                              MEAN_FAULT_RATIO_8GPU, FaultEvent, FaultTrace,
+                              generate_trace, to_4gpu_trace)
 
 
 def test_bayes_split_constant():
@@ -84,3 +84,53 @@ def test_event_deltas_reconstruct_faulty_at():
             di += 1
         assert set(np.nonzero(counts > 0)[0].tolist()) == tr.faulty_at(t)
     assert np.all(counts >= 0)
+
+
+def _blocks(tr, ts, block):
+    parts = list(tr.fault_mask_blocks(ts, block))
+    assert all(p.shape == (min(block, len(ts) - i * block), tr.num_nodes)
+               for i, p in enumerate(parts))
+    return (np.concatenate(parts) if parts
+            else np.zeros((0, tr.num_nodes), bool))
+
+
+def _on_edges_trace():
+    """Overlapping events on one node (a background fault with a burst
+    inside it, and one starting where another ends) and events that start
+    or end exactly on the block edges of the tests below (t = 7, 14)."""
+    ev = [FaultEvent(0, 1.0, 9.0), FaultEvent(0, 3.0, 5.0),
+          FaultEvent(0, 9.0, 14.0), FaultEvent(1, 7.0, 14.0),
+          FaultEvent(2, 0.0, 7.0), FaultEvent(2, 6.5, 30.0),
+          FaultEvent(3, 14.0, 15.0), FaultEvent(1, 20.0, 21.0)]
+    return FaultTrace(5, 30.0, ev)
+
+
+@pytest.mark.parametrize("block", [1, 7, 1024, 100_000])
+@pytest.mark.parametrize("which", ["appendix_a", "on_edges", "empty"])
+def test_fault_mask_blocks_equal_whole_masks(block, which):
+    """Blocks of any size concatenate to ``fault_masks`` bit for bit, and
+    every row is the scalar ``faulty_at`` set."""
+    if which == "appendix_a":
+        tr = to_4gpu_trace(generate_trace(40, horizon_h=20 * 24.0, seed=3),
+                           seed=3)
+        ts = tr.interval_edges()
+    elif which == "on_edges":
+        tr = _on_edges_trace()
+        ts = np.arange(0.0, 28.0)          # integer hours: edges 7, 14, ...
+    else:
+        tr = FaultTrace(6, 10.0, [])
+        ts = np.arange(0.0, 10.0, 0.5)
+    whole = tr.fault_masks(ts)
+    assert np.array_equal(_blocks(tr, ts, block), whole)
+    for i, t in enumerate(ts):
+        assert set(np.nonzero(whole[i])[0].tolist()) == tr.faulty_at(t)
+
+
+def test_fault_mask_blocks_no_sample_times_and_bad_input():
+    tr = _on_edges_trace()
+    assert list(tr.fault_mask_blocks([], 4)) == []
+    assert tr.fault_masks([]).shape == (0, 5)
+    with pytest.raises(ValueError, match="ascending"):
+        list(tr.fault_mask_blocks([2.0, 1.0], 4))
+    with pytest.raises(ValueError, match="positive"):
+        list(tr.fault_mask_blocks([1.0], 0))
