@@ -10,9 +10,12 @@ one ``evaluate_masks`` call -- on the JAX backend that means thousands of
 seconds, bit-for-bit equal to the scalar event-by-event replay
 (``benchmarks/churn.py`` gates the >= 10x throughput claim).  For
 horizons or ensembles too large to concatenate, ``engine="streamed"``
-builds each realization's masks block by block and re-chunks them through
-``evaluate_mask_stream`` in bounded memory with the same bit-for-bit grids
-(``tests/test_stream.py``).
+treats the realizations as one stream of intervals in blocks, with the
+same bit-for-bit grids in bounded memory (``tests/test_stream.py``): on
+the JAX backend each block goes to the device as its sorted occupancy
+deltas and its masks are built there (``evaluate_delta_stream``); on
+NumPy each realization's masks are built block by block on the host and
+re-chunked through ``evaluate_mask_stream``.
 """
 
 from __future__ import annotations
@@ -24,9 +27,11 @@ from typing import (Callable, Dict, Iterator, List, Optional, Sequence,
 import numpy as np
 
 from .. import obs
-from ..core.trace import FaultTrace, generate_trace, to_4gpu_trace
+from ..core.trace import (DeltaBlock, FaultTrace, generate_trace,
+                          interval_delta_blocks, to_4gpu_trace)
 from ..obs.progress import Progress
-from ..sim.engine import evaluate_mask_stream, evaluate_masks
+from ..sim.engine import (evaluate_delta_stream, evaluate_mask_stream,
+                          evaluate_masks, resolve_backend)
 from ..sim.scenario import DEFAULT_ARCHITECTURES, make_model
 from .replay import replay_trace
 from .timeline import ChurnTimeline
@@ -143,6 +148,19 @@ def _mask_blocks(realizations: Sequence[Tuple[FaultTrace, np.ndarray]],
             yield masks
 
 
+def _delta_blocks(realizations: Sequence[Tuple[FaultTrace, np.ndarray]],
+                  rows: int, nodes: int) -> Iterator[DeltaBlock]:
+    """Every realization's interval masks in turn, as one stream of
+    occupancy-delta recipes of ``rows`` intervals each (the device builds
+    the masks), each sliced and padded under a ``churn.masks`` span."""
+    total = sum(len(edges) for _, edges in realizations)
+    blocks = interval_delta_blocks(realizations, rows)
+    for lo in range(0, total, rows):
+        with obs.span("churn.masks", rows=min(rows, total - lo), nodes=nodes):
+            block = next(blocks)
+        yield block
+
+
 def monte_carlo_replay(spec: ChurnSpec,
                        traces: Union[int, Sequence[FaultTrace]], *,
                        engine: str = "batched", backend: str = "auto",
@@ -155,12 +173,15 @@ def monte_carlo_replay(spec: ChurnSpec,
     pre-generated sequence of :class:`FaultTrace` (the benchmarks pass one
     so engine timing excludes trace generation).  ``engine="batched"``
     evaluates ALL realizations' interval masks in a single scenario-engine
-    pass; ``engine="streamed"`` produces bit-identical timelines but builds
-    each realization's masks ``chunk_snapshots`` intervals at a time
-    (``FaultTrace.fault_mask_blocks``) and feeds them through
-    ``evaluate_mask_stream`` (re-chunked across realization boundaries),
-    bounding peak host mask memory at about one mask block plus one
-    evaluation block whatever the horizon or the ensemble size;
+    pass; ``engine="streamed"`` produces bit-identical timelines in blocks
+    of ``chunk_snapshots`` intervals of the realizations' joint stream,
+    bounding peak mask memory at about one block whatever the horizon or
+    the ensemble size.  On the JAX backend each block's masks are built on
+    the device from its occupancy deltas
+    (``repro.core.trace.interval_delta_blocks``, ``evaluate_delta_stream``);
+    on NumPy each realization's masks are built on the host
+    (``FaultTrace.fault_mask_blocks``) and fed through
+    ``evaluate_mask_stream``, re-chunked across realization boundaries.
     ``engine="scalar"`` loops the event-by-event reference replay.
 
     ``progress`` (``engine="streamed"`` only) is forwarded to
@@ -186,11 +207,20 @@ def monte_carlo_replay(spec: ChurnSpec,
         intervals = int(sum(len(e) for _, e in realizations))
         if engine == "streamed":
             chunk_snapshots = max(1, chunk_snapshots)
-            total, faulty, placed, chosen = evaluate_mask_stream(
-                models, spec.tp_sizes,
-                _mask_blocks(realizations, chunk_snapshots), intervals,
-                chunk_snapshots=chunk_snapshots, backend=backend,
-                progress=progress)
+            if resolve_backend(backend, models) == "jax":
+                width = (realizations[0][0].num_nodes if realizations
+                         else spec.num_nodes)
+                total, faulty, placed, chosen = evaluate_delta_stream(
+                    models, spec.tp_sizes, width,
+                    _delta_blocks(realizations, chunk_snapshots, width),
+                    intervals, chunk_snapshots=chunk_snapshots,
+                    progress=progress)
+            else:
+                total, faulty, placed, chosen = evaluate_mask_stream(
+                    models, spec.tp_sizes,
+                    _mask_blocks(realizations, chunk_snapshots), intervals,
+                    chunk_snapshots=chunk_snapshots, backend=backend,
+                    progress=progress)
         else:
             if realizations:
                 masks = np.concatenate([tr.fault_masks(e)

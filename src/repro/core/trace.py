@@ -69,45 +69,47 @@ class FaultTrace:
 
         Yields ``(rows, num_nodes)`` bool matrices whose concatenation is
         ``fault_masks(ts)`` bit for bit, so a long horizon never holds more
-        than one block.  Each block starts from every node's active-event
-        count at its first sample time, carried from the blocks before it:
-        the events whose start (end) index falls inside a block are a slice
-        of the events sorted by that index.
+        than one block.  Each block is the host cumsum of its slice of
+        :meth:`interval_deltas` (:func:`interval_delta_blocks`), started
+        from every node's active-event count carried from the blocks
+        before it -- the same recipe the JAX churn stream builds on the
+        device.
+        """
+        if block < 1:
+            raise ValueError(f"block must be positive, got {block}")
+        carry = np.zeros(self.num_nodes, dtype=np.int16)
+        for recipe in interval_delta_blocks([(self, ts)], block):
+            out, carry = recipe.host_masks(self.num_nodes, carry)
+            yield out
+
+    def interval_deltas(self, ts: Sequence[float]
+                        ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Occupancy deltas of the sample times ``ts``: ``(row, node,
+        step)`` int64 arrays, sorted by row.
+
+        An event covers the rows ``i0 <= i < i1``, where ``i0`` and ``i1``
+        are the ``side="left"`` searchsorted indices of its start and end in
+        ``ts`` (the ``start <= t < end`` test of :meth:`faulty_at`), so it
+        adds ``(i0, node, +1)`` and ``(i1, node, -1)``; an event covering no
+        row adds nothing.  Row ``i`` of ``fault_masks(ts)`` is ``count >
+        0``, ``count`` being the per-node sum of the steps of rows ``<= i``.
+        An event still active at the last sample ends on row ``len(ts)``.
         """
         ts = np.asarray(ts, dtype=np.float64)
         if len(ts) > 1 and np.any(np.diff(ts) < 0):
             raise ValueError("fault_masks requires ascending sample times "
                              "(searchsorted semantics)")
-        if block < 1:
-            raise ValueError(f"block must be positive, got {block}")
         starts = np.array([e.start_h for e in self.events], dtype=np.float64)
         ends = np.array([e.end_h for e in self.events], dtype=np.float64)
         nodes = np.array([e.node for e in self.events], dtype=np.int64)
-        # event active at ts[i] iff i >= searchsorted(start) and i < searchsorted(end)
         i0 = np.searchsorted(ts, starts, side="left")
         i1 = np.searchsorted(ts, ends, side="left")
-        by0, by1 = np.argsort(i0, kind="stable"), np.argsort(i1, kind="stable")
-        sides = ((i0[by0], nodes[by0], 1), (i1[by1], nodes[by1], -1))
-        carry = np.zeros(self.num_nodes, dtype=np.int16)
-        for lo in range(0, len(ts), block):
-            hi = min(lo + block, len(ts))
-            # int16 + in-place cumsum keeps the peak footprint at ~2x the
-            # bool mask even for 100k-node x multi-thousand-snapshot grids
-            # (the count is concurrently-active events per node, far below
-            # the int16 range); the (node, time) layout makes the cumsum
-            # contiguous (~4x faster than accumulating down the snapshot
-            # axis)
-            delta = np.zeros((self.num_nodes, hi - lo), dtype=np.int16)
-            delta[:, 0] = carry
-            for index, who, step in sides:
-                a, b = np.searchsorted(index, (lo, hi), side="left")
-                np.add.at(delta, (who[a:b], index[a:b] - lo), step)
-            np.cumsum(delta, axis=1, out=delta)
-            carry = delta[:, -1].copy()
-            out = np.empty((hi - lo, self.num_nodes), dtype=bool)
-            np.greater(delta.T, 0, out=out)    # one C-ordered allocation
-            del delta
-            yield out
+        live = i0 < i1
+        row = np.concatenate([i0[live], i1[live]])
+        node = np.concatenate([nodes[live], nodes[live]])
+        step = np.repeat(np.array([1, -1], dtype=np.int64), live.sum())
+        order = np.argsort(row, kind="stable")
+        return row[order], node[order], step[order]
 
     def interval_edges(self) -> np.ndarray:
         """Left edges of the piecewise-constant fault-set intervals.
@@ -164,6 +166,96 @@ class FaultTrace:
         if not self.events:
             return 0.0
         return float(np.mean([e.end_h - e.start_h for e in self.events]))
+
+
+#: Occupancy deltas per device build call (``repro.sim.jax_backend``'s
+#: ``jit_build_interval_masks``): a block with more is built in several
+#: calls of the same program.  Three int32 per delta: 48 KB per call.
+DELTA_CAPACITY = 4096
+
+
+@dataclasses.dataclass(frozen=True)
+class DeltaBlock:
+    """One block of a stream of interval masks, as the occupancy deltas
+    that build it from the per-node counts carried into it.
+
+    ``chunks[j]`` is a ``(3, capacity)`` int32 array of ``(row in block,
+    node, step)`` deltas, sorted by row; pad entries are ``(0, 0, 0)``.
+    Building chunk ``j`` rewrites the rows from ``starts[j]`` on: ``0`` for
+    the first chunk, then the row of the chunk's first delta, so a row is
+    last written by the chunk that holds every delta at or before it.
+    """
+
+    rows: int
+    chunks: np.ndarray   # (k >= 1, 3, capacity) int32
+    starts: np.ndarray   # (k,) int32
+
+    @classmethod
+    def pack(cls, rows: int, row: np.ndarray, node: np.ndarray,
+             step: np.ndarray, capacity: int = DELTA_CAPACITY) -> "DeltaBlock":
+        """The block of ``rows`` rows whose sorted deltas are ``(row, node,
+        step)``, padded to whole chunks of ``capacity``."""
+        k = max(1, -(-len(row) // capacity))
+        flat = np.zeros((3, k * capacity), dtype=np.int32)
+        flat[0, :len(row)], flat[1, :len(row)], flat[2, :len(row)] = \
+            row, node, step
+        starts = np.zeros(k, dtype=np.int32)
+        starts[1:] = flat[0, capacity:k * capacity:capacity]
+        chunks = np.ascontiguousarray(
+            flat.reshape(3, k, capacity).transpose(1, 0, 2))
+        return cls(rows, chunks, starts)
+
+    def host_masks(self, num_nodes: int, carry: np.ndarray
+                   ) -> Tuple[np.ndarray, np.ndarray]:
+        """``(rows, num_nodes)`` bool masks and the counts carried out,
+        from the int16 per-node counts ``carry`` carried in."""
+        # int16 + in-place cumsum keeps the peak footprint at ~2x the bool
+        # mask even for 100k-node x multi-thousand-snapshot grids (the
+        # count is concurrently-active events per node, far below the int16
+        # range); the (node, time) layout makes the cumsum contiguous (~4x
+        # faster than accumulating down the snapshot axis)
+        delta = np.zeros((num_nodes, self.rows), dtype=np.int16)
+        delta[:, 0] = carry
+        row, node, step = self.chunks.transpose(1, 0, 2).reshape(3, -1)
+        np.add.at(delta, (node, row), step.astype(np.int16))
+        np.cumsum(delta, axis=1, out=delta)
+        out = np.empty((self.rows, num_nodes), dtype=bool)
+        np.greater(delta.T, 0, out=out)        # one C-ordered allocation
+        return out, delta[:, -1].copy()
+
+
+def interval_delta_blocks(pieces: Sequence[Tuple[FaultTrace,
+                                                 Sequence[float]]],
+                          block: int, capacity: int = DELTA_CAPACITY
+                          ) -> Iterator[DeltaBlock]:
+    """The masks of every ``(trace, sample times)`` piece in turn, as one
+    stream of rows cut into blocks of ``block`` rows (the last may be
+    shorter), each a :class:`DeltaBlock`.
+
+    Piece ``p`` starts at row ``off_p`` of the stream and its
+    :meth:`FaultTrace.interval_deltas` are shifted by it.  An event still
+    active at the piece's last sample ends on the first row of the next
+    piece, which resets every count there, so a block that straddles two
+    pieces needs nothing of its own; deltas past the stream are dropped.
+    """
+    if block < 1:
+        raise ValueError(f"block must be positive, got {block}")
+    parts = [tr.interval_deltas(ts) for tr, ts in pieces]
+    sizes = [len(ts) for _, ts in pieces]
+    offsets = np.concatenate([[0], np.cumsum(sizes, dtype=np.int64)])
+    total = int(offsets[-1])
+    if not total:
+        return
+    row = np.concatenate([p[0] + off for p, off in zip(parts, offsets)])
+    node = np.concatenate([p[1] for p in parts])
+    step = np.concatenate([p[2] for p in parts])
+    order = np.argsort(row, kind="stable")
+    row, node, step = row[order], node[order], step[order]
+    for lo in range(0, total, block):
+        hi = min(lo + block, total)
+        a, b = np.searchsorted(row, (lo, hi), side="left")
+        yield DeltaBlock.pack(hi - lo, row[a:b] - lo, node[a:b], step[a:b],
+                              capacity)
 
 
 def generate_trace(num_nodes: int, horizon_h: float = 348 * 24.0,

@@ -43,7 +43,10 @@ Spans of the path from a spec to its tables, outermost first:
     4-GPU split and interval edges; counters ``churn.fault_events`` and
     ``churn.intervals``), ``churn.monte_carlo_replay`` holding the
     streamed evaluation with one ``churn.masks`` per block of interval
-    masks, then ``churn.tables`` around the timeline slicing and each
+    masks (on the JAX backend: slicing the block's occupancy deltas, whose
+    masks are built on the device under ``churn.device_masks`` inside the
+    block's ``sim.jax.eval_block``; counter ``churn.device_masks_built``),
+    then ``churn.tables`` around the timeline slicing and each
     ``integrated_waste_table`` / ``ChurnEnsemble.summary_table``.
 """
 

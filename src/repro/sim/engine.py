@@ -37,6 +37,7 @@ import numpy as np
 from .. import obs
 from ..core.hbd_models import HBDModel
 from ..core.prng import counter_fault_masks
+from ..core.trace import DeltaBlock
 from ..obs.progress import Progress, StreamProgress
 from .scenario import CounterIIDSnapshots, ScenarioSpec
 
@@ -249,6 +250,56 @@ def evaluate_mask_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
         raise ValueError(f"mask stream yielded {state['lo']} snapshots, "
                          f"expected {total_snapshots}")
     return total, faulty, placed, chosen
+
+
+def evaluate_delta_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
+                          width: int, blocks: Iterable[DeltaBlock],
+                          total_snapshots: int, *, chunk_snapshots: int = 1024,
+                          progress: Optional[Callable[[Progress], None]] = None
+                          ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, str]:
+    """:func:`evaluate_mask_stream` for an interval stream whose masks are
+    built on the device (JAX backend only).
+
+    ``blocks`` are the stream's blocks of ``chunk_snapshots`` rows (the
+    last may be shorter) as occupancy-delta recipes
+    (``repro.core.trace.interval_delta_blocks``) over ``width`` nodes.
+    They flow through one ``repro.sim.jax_backend.GridEvaluator``, which
+    carries every node's count on the device from block to block and pads
+    the short last block, so the stream runs one compiled shape.  The
+    grids, and ``progress``, are those of :func:`evaluate_mask_stream` on
+    the same masks.
+    """
+    from . import jax_backend
+    tp_sizes = list(tp_sizes)
+    a_count, t_count = len(models), len(tp_sizes)
+    total = np.zeros((a_count, t_count), dtype=np.int64)
+    faulty = np.zeros((a_count, total_snapshots, t_count), dtype=np.int64)
+    placed = np.zeros((a_count, total_snapshots, t_count), dtype=np.int64)
+    chunk_snapshots = max(1, chunk_snapshots)
+    tracker = StreamProgress(total_snapshots, progress, prefix="sim.stream")
+    evaluator = None
+    lo = 0
+    with obs.span("sim.evaluate_delta_stream", backend="jax",
+                  snapshots=total_snapshots):
+        for block in blocks:
+            if evaluator is None:
+                with obs.span("sim.jax.setup", rows=total_snapshots):
+                    evaluator = jax_backend.GridEvaluator(
+                        models, tp_sizes, width,
+                        rows=min(chunk_snapshots, total_snapshots))
+                    total[:] = evaluator.totals()
+            with obs.span("sim.stream.block", rows=block.rows, offset=lo,
+                          backend="jax"):
+                obs.count("sim.snapshots_evaluated", block.rows)
+                f, p = evaluator.eval_deltas(block)
+            faulty[:, lo:lo + block.rows] = f
+            placed[:, lo:lo + block.rows] = p
+            lo += block.rows
+            tracker.update(block.rows)
+    if lo != total_snapshots:
+        raise ValueError(f"delta stream yielded {lo} snapshots, "
+                         f"expected {total_snapshots}")
+    return total, faulty, placed, "jax"
 
 
 def run_sweep(spec: ScenarioSpec, *, masks: Optional[np.ndarray] = None,

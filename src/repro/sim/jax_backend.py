@@ -21,15 +21,21 @@ Guarantees (enforced by ``tests/test_jax_backend.py``):
     ``repro.core.prng.counter_lanes``, bit-identical to the host's
     ``repro.core.prng.counter_fault_masks`` whatever
     ``jax_threefry_partitionable`` says, and the block goes from the draw
-    into the grid program without leaving the device.  Every other mask
-    source is drawn on the host and copied over block by block.
+    into the grid program without leaving the device;
+  * interval masks of a churn stream are built on the device from the
+    sorted occupancy deltas of ``repro.core.trace.interval_delta_blocks``
+    (``_interval_fn``), the per-node counts carried on the device from
+    block to block, bit-identical to ``FaultTrace.fault_mask_blocks``.
+    Every other mask source is drawn on the host and copied over block by
+    block.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import warnings
-from typing import Callable, Dict, Optional, Sequence, Tuple, Type
+from typing import (Callable, Dict, Iterable, Iterator, Optional, Sequence,
+                    Tuple, Type)
 
 import numpy as np
 
@@ -46,6 +52,7 @@ except Exception as e:  # pragma: no cover - exercised on jax-free installs
 
 from .. import obs
 from ..core import prng as cprng
+from ..core.trace import DeltaBlock
 from ..faults.jax_mirror import threefry2x32_jnp
 from ..runtime import engine_devices, snapshot_mesh
 from ..core.hbd_models import (BigSwitch, HBDModel, InfiniteHBDModel,
@@ -306,6 +313,54 @@ def _threshold_args(ratio: float) -> Tuple[np.uint32, np.bool_]:
     return np.uint32(min(thresh, 0xFFFFFFFF)), np.bool_(thresh >= 1 << 32)
 
 
+_INTERVAL_CACHE: Dict[Tuple, Callable] = {}
+
+
+def _interval_fn(rows: int, width: int, mesh) -> Callable:
+    """Jitted ``(block (rows, width) bool, carry (width,) int32, start
+    int32, deltas (3, C) int32) -> (block', carry')`` build of interval
+    masks from occupancy deltas (module ``jit_build_interval_masks``).
+
+    ``deltas`` holds ``(row, node, step)`` entries of one
+    ``repro.core.trace.DeltaBlock`` chunk (pads step 0).  Each row's count
+    is ``carry`` plus the steps of the rows at or before it; ``block'`` is
+    ``count > 0`` from row ``start`` on and ``block`` before it, and
+    ``carry'`` is ``carry`` plus every step.  On a mesh each device builds
+    its own rows: the deltas of the rows before them go into its base
+    count, so no count crosses devices.  Counts are small integers, so the
+    int32 cumsum is exact.
+    """
+    key = (rows, width, mesh)
+    fn = _INTERVAL_CACHE.get(key)
+    if fn is not None:
+        return fn
+    ndev = 1 if mesh is None else mesh.devices.size
+    local = rows // ndev
+
+    def build_interval_masks(block, carry, start, deltas):
+        row, node, step = deltas[0], deltas[1], deltas[2]
+        first = 0 if mesh is None else jax.lax.axis_index(_SNAP_AXIS) * local
+        base = carry.at[node].add(jnp.where(row < first, step, 0))
+        at = row - first
+        mine = (at >= 0) & (at < local)
+        # deltas of other devices' rows land on a scratch row, dropped
+        steps = jnp.zeros((local + 1, width), jnp.int32).at[
+            jnp.where(mine, at, local), node].add(step)
+        counts = base + jnp.cumsum(steps[:local], axis=0)
+        kept = (first + jnp.arange(local, dtype=jnp.int32))[:, None] < start
+        return (jnp.where(kept, block, counts > 0),
+                carry.at[node].add(step))
+
+    build = build_interval_masks
+    if mesh is not None:
+        build = jax.shard_map(build, mesh=mesh,
+                              in_specs=(P(_SNAP_AXIS), P(), P(), P()),
+                              out_specs=(P(_SNAP_AXIS), P()))
+    fn = jax.jit(build)
+    _INTERVAL_CACHE[key] = fn
+    return fn
+
+
 _GRID_CACHE: Dict[Tuple, Callable] = {}
 
 
@@ -355,6 +410,47 @@ def _zero_snapshot_totals(models: Sequence[HBDModel],
         for m in models])
 
 
+class IntervalMaskSource:
+    """Builds the interval masks of one stream on the device, block by
+    block, from ``repro.core.trace.DeltaBlock`` recipes.
+
+    Every node's count is carried on the device from block to block,
+    starting at zero, so nothing per node crosses the host link.  Each
+    block comes out as a ``(rows, width)`` bool device array in the engine's
+    snapshot sharding: ``rows`` rows whatever the block's length (the rows
+    past it are not part of the stream), one compiled program
+    (:func:`_interval_fn`) for every block and chunk.
+    """
+
+    def __init__(self, rows: int, width: int, mesh):
+        ndev = 1 if mesh is None else mesh.devices.size
+        if rows % ndev:
+            raise ValueError(f"{rows} rows do not split over {ndev} devices")
+        self.rows = rows
+        self.replicated = None if mesh is None else NamedSharding(mesh, P())
+        self.fn = _interval_fn(rows, width, mesh)
+        self.blank = jnp.zeros(
+            (rows, width), bool,
+            device=None if mesh is None else NamedSharding(mesh,
+                                                           P(_SNAP_AXIS)))
+        self.carry = jnp.zeros((width,), jnp.int32, device=self.replicated)
+
+    def put(self, block: DeltaBlock) -> list:
+        """The block's chunks of deltas, copied to every device."""
+        if block.rows > self.rows:
+            raise ValueError(f"delta block of {block.rows} rows exceeds "
+                             f"the source's {self.rows}")
+        return [jax.device_put(c, self.replicated) for c in block.chunks]
+
+    def build(self, block: DeltaBlock, chunks: list):
+        """Dispatch the build of ``block`` from its :meth:`put` chunks; the
+        counts carried out are kept for the next block."""
+        out = self.blank
+        for start, deltas in zip(block.starts, chunks):
+            out, self.carry = self.fn(out, self.carry, start, deltas)
+        return out
+
+
 class GridEvaluator:
     """Reusable device grid evaluator bound to one ``(models, tps, width)``.
 
@@ -366,7 +462,11 @@ class GridEvaluator:
     ``evaluate_mask_stream`` drives one evaluator across an entire mask
     stream (million-snapshot Monte-Carlo) without ever materializing the
     full matrix on host or device.  ``rows`` is the caller's block size:
-    shorter blocks are padded up to it.
+    shorter blocks are padded up to it.  A block comes in one of three
+    kinds: a host bool mask matrix or a ``MaskGen`` index vector
+    (:meth:`eval_block`), or a ``repro.core.trace.DeltaBlock`` of an
+    interval stream, built into masks on the device
+    (:meth:`eval_deltas`, ``repro.sim.engine.evaluate_delta_stream``).
     """
 
     def __init__(self, models: Sequence[HBDModel], tps: Sequence[int],
@@ -384,6 +484,7 @@ class GridEvaluator:
         self.sharding = (None if self.mesh is None
                          else NamedSharding(self.mesh, P(_SNAP_AXIS)))
         self.fn = _grid_fn(self.models, self.tps, self.mesh, width)
+        self.intervals = None            # eval_deltas' interval-mask source
         if gen is not None:
             self.draw = _draw_fn(width, self.mesh)
             self.root = cprng.threefry_seed(gen.seed)
@@ -435,17 +536,48 @@ class GridEvaluator:
                               nodes=self.width):
                     arg = self.draw(arg, *self.thresh)
                 obs.count("prng.device_masks_drawn", rows)
-            with warnings.catch_warnings():
-                # bool/int32 donation can't alias int32 outputs; the
-                # donation still releases the chunk buffer eagerly, which
-                # is the point
-                warnings.filterwarnings("ignore", message=".*onat.*buffer.*")
-                out = self.fn(arg)                 # (padded, A, 2, T)
-            jax.block_until_ready(out)
-            with obs.span("sim.jax.fetch", rows=rows):
-                out = np.asarray(out)
-                return (out[:rows, :, 0].transpose(1, 0, 2).astype(np.int64),
-                        out[:rows, :, 1].transpose(1, 0, 2).astype(np.int64))
+            return self._grids(arg, rows)
+
+    def eval_deltas(self, block: DeltaBlock
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Evaluate one block of an interval stream given as occupancy
+        deltas; returns int64 ``(faulty, placed)``, each ``(A, rows, T)``.
+
+        The block's masks are built on the device by the evaluator's
+        :class:`IntervalMaskSource` (made at the first block, so one
+        evaluator serves one stream) and go into the grid program without
+        leaving it.
+        A block shorter than ``rows`` runs the same shapes; its pad rows
+        are discarded.
+
+        Spans: as :meth:`eval_block`, with ``sim.jax.put`` copying the
+        deltas and ``churn.device_masks`` around the build's dispatch.
+        """
+        rows = block.rows
+        if self.intervals is None:
+            self.intervals = IntervalMaskSource(self.rows, self.width,
+                                                self.mesh)
+        with obs.span("sim.jax.eval_block", rows=rows, devices=self.ndev):
+            with obs.span("sim.jax.put", rows=rows,
+                          bytes=block.chunks.nbytes):
+                chunks = self.intervals.put(block)
+            with obs.span("churn.device_masks", rows=rows, nodes=self.width):
+                arg = self.intervals.build(block, chunks)
+            obs.count("churn.device_masks_built", rows)
+            return self._grids(arg, rows)
+
+    def _grids(self, arg, rows: int) -> Tuple[np.ndarray, np.ndarray]:
+        """The grid program on a device block, waited for and fetched."""
+        with warnings.catch_warnings():
+            # bool/int32 donation can't alias int32 outputs; the donation
+            # still releases the chunk buffer eagerly, which is the point
+            warnings.filterwarnings("ignore", message=".*onat.*buffer.*")
+            out = self.fn(arg)                     # (padded, A, 2, T)
+        jax.block_until_ready(out)
+        with obs.span("sim.jax.fetch", rows=rows):
+            out = np.asarray(out)
+            return (out[:rows, :, 0].transpose(1, 0, 2).astype(np.int64),
+                    out[:rows, :, 1].transpose(1, 0, 2).astype(np.int64))
 
 
 def sweep_grids(models: Sequence[HBDModel], tps: Sequence[int], *,
@@ -505,11 +637,30 @@ def counter_masks_device(gen: MaskGen, start: int = 0) -> np.ndarray:
                            *_threshold_args(gen.fault_ratio)))
 
 
+def interval_masks_device(blocks: Iterable[DeltaBlock], width: int,
+                          rows: int) -> Iterator[np.ndarray]:
+    """The masks of an interval stream's delta ``blocks`` (of up to
+    ``rows`` rows each), built on the device by the churn stream's own
+    program over the engine's devices and returned block by block as host
+    bool matrices (for tests and tools).  Bit-identical to
+    ``repro.core.trace.FaultTrace.fault_mask_blocks``."""
+    if not HAVE_JAX:
+        raise RuntimeError(f"jax unavailable ({_IMPORT_ERROR!r})")
+    mesh = snapshot_mesh(_SNAP_AXIS)
+    ndev = 1 if mesh is None else mesh.devices.size
+    source = IntervalMaskSource(-(-max(1, rows) // ndev) * ndev, width,
+                                mesh)
+    for block in blocks:
+        out = source.build(block, source.put(block))
+        yield np.asarray(out)[:block.rows]
+
+
 def num_devices() -> int:
     return len(engine_devices()) if HAVE_JAX else 0
 
 
 __all__ = [
     "HAVE_JAX", "GridEvaluator", "MaskGen", "available_for", "require",
-    "sweep_grids", "counter_masks_device", "num_devices",
+    "IntervalMaskSource", "sweep_grids", "counter_masks_device",
+    "interval_masks_device", "num_devices",
 ]

@@ -12,6 +12,8 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 
 from repro.churn.monte_carlo import ChurnSpec, monte_carlo_replay  # noqa: E402
+from repro.core.trace import interval_delta_blocks  # noqa: E402
+from repro.sim.jax_backend import interval_masks_device  # noqa: E402
 from repro.sim.engine import evaluate_mask_stream, evaluate_masks, run_sweep  # noqa: E402
 from repro.sim.scenario import CounterIIDSnapshots, ScenarioSpec  # noqa: E402
 
@@ -45,7 +47,8 @@ def main():
     assert np.array_equal(sgot.faulty_gpus, sref.faulty_gpus)
     assert np.array_equal(sgot.placed_gpus, sref.placed_gpus)
 
-    # streamed Monte-Carlo churn, sharded jax vs batched numpy
+    # streamed Monte-Carlo churn, sharded jax (masks built on the
+    # devices) vs batched numpy
     cspec = ChurnSpec(trace_nodes=40, horizon_h=24.0 * 20, tp_sizes=(16,),
                       architectures=ARCHES, seed=2)
     cref = monte_carlo_replay(cspec, 2, engine="batched", backend="numpy")
@@ -55,6 +58,17 @@ def main():
         assert np.array_equal(tg.faulty_gpus, tr.faulty_gpus)
         assert np.array_equal(tg.placed_gpus, tr.placed_gpus)
         assert np.array_equal(tg.total_gpus, tr.total_gpus)
+
+    # interval masks built on the device, each block's rows split over
+    # the eight devices (7-row blocks pad to 8), deltas beyond one call
+    trs = [cspec.trace(r) for r in range(2)]
+    pieces = [(tr, tr.interval_edges()) for tr in trs]
+    want = np.concatenate([tr.fault_masks(ts) for tr, ts in pieces])
+    for block, capacity in ((7, 3), (64, 4096)):
+        got = np.concatenate(list(interval_masks_device(
+            interval_delta_blocks(pieces, block, capacity),
+            trs[0].num_nodes, block)))
+        assert np.array_equal(got, want), (block, capacity)
 
     print("OK stream_sharded")
 

@@ -483,6 +483,45 @@ def test_churn_streamed_spans_and_counters(tel):
         == n_tables + 2 == 3
 
 
+@pytest.mark.skipif(not jax_backend.HAVE_JAX, reason="jax unavailable")
+def test_churn_device_masks_span_and_counter(tel):
+    """On the JAX backend the streamed replay slices each block's deltas
+    under ``churn.masks`` (rows, nodes) and builds its masks on the device
+    under ``churn.device_masks`` (rows, nodes), inside the block's
+    ``sim.jax.eval_block``; ``churn.device_masks_built`` counts the rows
+    and ``sim.jax.put`` copies the deltas."""
+    spec = ChurnSpec(trace_nodes=24, horizon_h=10 * 24.0, tp_sizes=(16,),
+                     architectures=ARCHES, seed=3)
+    ens = monte_carlo_replay(spec, 2, engine="streamed", backend="jax",
+                             chunk_snapshots=16)
+    total = sum(tl.num_intervals for tl in ens.timelines)
+    blocks = -(-total // 16)            # one stream across realizations
+    rows = [min(16, total - lo) for lo in range(0, total, 16)]
+    masks = _named(tel, "churn.masks")
+    built = _named(tel, "churn.device_masks")
+    evals = _named(tel, "sim.jax.eval_block")
+    assert [sp.attrs["rows"] for sp in masks] == rows
+    assert [sp.attrs["rows"] for sp in built] == rows
+    assert all(sp.attrs["nodes"] == spec.num_nodes for sp in masks + built)
+    assert len(evals) == blocks
+    assert all(_within(b, e) for b, e in zip(built, evals))
+    assert obs.summary()["counters"]["churn.device_masks_built"] == total
+    # one chunk of (row, node, step) int32 deltas per block
+    from repro.core.trace import DELTA_CAPACITY
+    puts = _named(tel, "sim.jax.put")
+    assert [sp.attrs["bytes"] for sp in puts] == [12 * DELTA_CAPACITY] * blocks
+
+
+@pytest.mark.skipif(not jax_backend.HAVE_JAX, reason="jax unavailable")
+def test_interval_build_carries_its_module_name():
+    import jax
+    text = jax_backend._interval_fn(16, 64, None).lower(
+        jax.ShapeDtypeStruct((16, 64), bool),
+        jax.ShapeDtypeStruct((64,), np.int32), np.int32(0),
+        jax.ShapeDtypeStruct((3, 32), np.int32)).compile().as_text()
+    assert "HloModule jit_build_interval_masks" in text
+
+
 # ------------------------------------------------- stragglers
 
 

@@ -197,6 +197,137 @@ def test_stream_jax_backend_runs_one_compiled_shape():
     assert compiles == []
 
 
+# the same three realizations as above, their masks built on the device
+@pytest.mark.parametrize("chunk_snapshots", [1, 37, 100, 226, 300, 100_000])
+def test_monte_carlo_streamed_jax_builds_the_masks(chunk_snapshots):
+    """``engine="streamed"`` on the JAX backend builds the interval masks
+    on the device from the stream's deltas: the same grids as the NumPy
+    streamed and batched engines."""
+    pytest.importorskip("jax")
+    spec = ChurnSpec(trace_nodes=60, horizon_h=24.0 * 30, tp_sizes=(16, 32),
+                     architectures=ARCHES, seed=7)
+    ref = monte_carlo_replay(spec, 3, engine="batched", backend="numpy")
+    host = monte_carlo_replay(spec, 3, engine="streamed", backend="numpy",
+                              chunk_snapshots=chunk_snapshots)
+    got = monte_carlo_replay(spec, 3, engine="streamed", backend="jax",
+                             chunk_snapshots=chunk_snapshots)
+    assert got.backend == "jax" and got.num_traces == 3
+    for tg, th, tr in zip(got.timelines, host.timelines, ref.timelines):
+        assert np.array_equal(tg.edges_h, tr.edges_h)
+        for name in ("total_gpus", "faulty_gpus", "placed_gpus"):
+            assert np.array_equal(getattr(tg, name), getattr(tr, name))
+            assert np.array_equal(getattr(th, name), getattr(tr, name))
+
+
+def test_monte_carlo_streamed_jax_empty_and_short():
+    pytest.importorskip("jax")
+    spec = ChurnSpec(trace_nodes=40, horizon_h=24.0, tp_sizes=(16,),
+                     architectures=ARCHES, seed=1)
+    assert monte_carlo_replay(spec, 0, engine="streamed",
+                              backend="jax").num_traces == 0
+    ref = monte_carlo_replay(spec, 1, engine="batched", backend="numpy")
+    got = monte_carlo_replay(spec, 1, engine="streamed", backend="jax",
+                             chunk_snapshots=4096)
+    assert np.array_equal(got.timelines[0].placed_gpus,
+                          ref.timelines[0].placed_gpus)
+
+
+def test_delta_stream_length_mismatch_raises():
+    pytest.importorskip("jax")
+    from repro.core.trace import interval_delta_blocks
+    from repro.sim.engine import evaluate_delta_stream
+    spec = ChurnSpec(trace_nodes=20, horizon_h=48.0, tp_sizes=(16,),
+                     architectures=ARCHES, seed=2)
+    tr = spec.trace(0)
+    edges = tr.interval_edges()
+    blocks = interval_delta_blocks([(tr, edges)], 8)
+    with pytest.raises(ValueError, match=f"yielded {len(edges)}"):
+        evaluate_delta_stream(spec.models(), spec.tp_sizes, tr.num_nodes,
+                              blocks, len(edges) + 1, chunk_snapshots=8)
+
+
+def test_delta_stream_runs_one_compiled_shape():
+    """After one churn stream has compiled its block, streams of other
+    lengths and realizations (short last blocks, blocks straddling
+    realizations, chunks of deltas beyond one call) compile nothing."""
+    pytest.importorskip("jax")
+    from jax import monitoring
+    from repro.core import trace as ctrace
+    events = ("/jax/core/compile/backend_compile_duration",
+              "/jax/compilation_cache/cache_retrieval_time_sec")
+    compiles = []
+
+    def listen(event, duration, **_):
+        if event in events:
+            compiles.append(event)
+
+    def spec(seed, days):
+        return ChurnSpec(trace_nodes=50, horizon_h=24.0 * days,
+                         tp_sizes=(16, 32), architectures=ARCHES, seed=seed)
+
+    warm = monte_carlo_replay(spec(1, 4), 1, engine="streamed",
+                              backend="jax", chunk_snapshots=16)
+    assert warm.timelines[0].num_intervals > 16
+    monitoring.register_event_duration_secs_listener(listen)
+    try:
+        for seed, days, count in ((5, 9, 2), (6, 4, 3)):
+            ref = monte_carlo_replay(spec(seed, days), count,
+                                     engine="batched", backend="numpy")
+            got = monte_carlo_replay(spec(seed, days), count,
+                                     engine="streamed", backend="jax",
+                                     chunk_snapshots=16)
+            for tg, tr in zip(got.timelines, ref.timelines):
+                assert np.array_equal(tg.faulty_gpus, tr.faulty_gpus)
+                assert np.array_equal(tg.placed_gpus, tr.placed_gpus)
+        # 4 deltas per call: most blocks take several calls
+        blocks = ctrace.interval_delta_blocks(
+            [(spec(7, 3).trace(0), spec(7, 3).trace(0).interval_edges())],
+            16, capacity=4)
+        assert max(len(b.starts) for b in blocks) > 1
+    finally:
+        monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+
+
+def _end_inclusive(monkeypatch):
+    """Every fault also covers the sample at its end time."""
+    from repro.core.trace import FaultEvent, FaultTrace
+    real = FaultTrace.interval_deltas
+
+    def deltas(self, ts):
+        later = [FaultEvent(e.node, e.start_h, np.nextafter(e.end_h, np.inf))
+                 for e in self.events]
+        return real(FaultTrace(self.num_nodes, self.horizon_h, later), ts)
+    monkeypatch.setattr(FaultTrace, "interval_deltas", deltas)
+
+
+def _carry_dropped(monkeypatch):
+    """Every block starts from no active events."""
+    import jax.numpy as jnp
+    from repro.sim.jax_backend import IntervalMaskSource
+    real = IntervalMaskSource.build
+
+    def build(self, block, chunks):
+        self.carry = jnp.zeros_like(self.carry)
+        return real(self, block, chunks)
+    monkeypatch.setattr(IntervalMaskSource, "build", build)
+
+
+@pytest.mark.parametrize("plant", [_end_inclusive, _carry_dropped])
+def test_planted_device_recipe_fault_changes_the_grids(plant, monkeypatch):
+    """The equalities above would see a wrong device recipe: an end
+    treated as inclusive, or the counts dropped between blocks."""
+    pytest.importorskip("jax")
+    spec = ChurnSpec(trace_nodes=60, horizon_h=24.0 * 30, tp_sizes=(16, 32),
+                     architectures=ARCHES, seed=7)
+    ref = monte_carlo_replay(spec, 2, engine="batched", backend="numpy")
+    plant(monkeypatch)
+    got = monte_carlo_replay(spec, 2, engine="streamed", backend="jax",
+                             chunk_snapshots=37)
+    assert any(not np.array_equal(tg.faulty_gpus, tr.faulty_gpus)
+               for tg, tr in zip(got.timelines, ref.timelines))
+
+
 @pytest.mark.slow
 def test_stream_sharded_subprocess():
     """Streaming equality under forced 8-device sharding (subprocess so the

@@ -101,6 +101,27 @@ def test_mask_draw_compiles(topo, chips):
     assert mem.output_size_in_bytes == ROWS * 32_768 // chips
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_interval_build_compiles(topo, chips):
+    """The churn stream's interval-mask build of one block at 131,072
+    GPUs: a chunk of deltas and the carried counts in, the bool block out,
+    its rows split over the chips."""
+    from repro.core.trace import DELTA_CAPACITY
+    sharding = _sharding(topo, chips)
+    mesh = None if chips == 1 else sharding.mesh
+    one = _sharding(topo, 1) if chips == 1 else NamedSharding(mesh, P())
+    fn = sim_jax._interval_fn(ROWS, 32_768, mesh)
+    block = jax.ShapeDtypeStruct((ROWS, 32_768), bool, sharding=sharding)
+    carry = jax.ShapeDtypeStruct((32_768,), jnp.int32, sharding=one)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=one)
+    deltas = jax.ShapeDtypeStruct((3, DELTA_CAPACITY), jnp.int32,
+                                  sharding=one)
+    mem = fn.lower(block, carry, start, deltas).compile().memory_analysis()
+    # the block and the counts, plus the output tuple's table
+    out = ROWS * 32_768 // chips + 32_768 * 4
+    assert out <= mem.output_size_in_bytes <= out + 1024
+
+
 def test_dcn_program_compiles(topo):
     spec = DcnSpec(num_nodes=256, tp_sizes=(32,), agg_domain=64)
     fn = dcn_jax._grid_fn(spec.config, (32,), (spec.job_gpus(32),), None)

@@ -11,7 +11,8 @@ import pytest
 
 from repro.core.trace import (BAYES_SPLIT_P, FAULT_RATIO_4GPU,
                               MEAN_FAULT_RATIO_8GPU, FaultEvent, FaultTrace,
-                              generate_trace, to_4gpu_trace)
+                              generate_trace, interval_delta_blocks,
+                              to_4gpu_trace)
 
 
 def test_bayes_split_constant():
@@ -134,3 +135,79 @@ def test_fault_mask_blocks_no_sample_times_and_bad_input():
         list(tr.fault_mask_blocks([2.0, 1.0], 4))
     with pytest.raises(ValueError, match="positive"):
         list(tr.fault_mask_blocks([1.0], 0))
+
+
+# ------------------------------------------- interval streams as deltas
+
+
+def _stream_pieces(which):
+    """``(trace, sample times)`` pieces of one interval stream."""
+    if which == "appendix_a":            # two realizations, one straddle
+        trs = [to_4gpu_trace(generate_trace(40, horizon_h=10 * 24.0, seed=s),
+                             seed=s) for s in (3, 4)]
+        return [(tr, tr.interval_edges()) for tr in trs]
+    if which == "on_edges":              # overlapping events on one node
+        tr = _on_edges_trace()
+        return [(tr, np.arange(0.0, 28.0)), (tr, np.arange(0.0, 30.0, 4.0))]
+    if which == "zero_length_clipped":
+        ev = [FaultEvent(0, 2.0, 2.0), FaultEvent(1, 2.5, 2.7),   # no row
+              FaultEvent(2, 8.0, 10.0), FaultEvent(3, 1.0, 10.0),  # clipped
+              FaultEvent(4, 9.5, 10.0), FaultEvent(0, 0.0, 1.0)]
+        tr = FaultTrace(6, 10.0, ev)
+        return [(tr, np.arange(0.0, 10.0)), (tr, np.arange(0.0, 9.0, 3.0))]
+    if which == "burst":                 # 40 starts and 40 ends on one row
+        ev = [FaultEvent(n, 3.0, 6.0) for n in range(40)]
+        ev += [FaultEvent(7, 1.0, 9.0), FaultEvent(7, 3.0, 4.0)]
+        tr = FaultTrace(48, 10.0, ev)
+        return [(tr, np.arange(0.0, 10.0)), (tr, np.arange(0.0, 10.0, 0.5))]
+    tr = FaultTrace(6, 10.0, [])
+    return [(tr, np.arange(0.0, 10.0, 0.5))]
+
+
+def _host_stream(pieces):
+    return np.concatenate([tr.fault_masks(ts) for tr, ts in pieces])
+
+
+@pytest.mark.parametrize("block", [1, 3, 32, 100_000])
+@pytest.mark.parametrize("which", ["appendix_a", "on_edges",
+                                   "zero_length_clipped", "burst", "empty"])
+def test_interval_delta_blocks_build_the_host_masks(block, which):
+    """A stream of pieces cut into delta blocks (8 deltas per chunk, so
+    the burst overflows a chunk at one row) builds, on the host and on
+    the device, exactly the concatenated ``fault_masks`` of the pieces."""
+    jax_backend = pytest.importorskip("repro.sim.jax_backend")
+    if not jax_backend.HAVE_JAX:
+        pytest.skip("jax unavailable")
+    pieces = _stream_pieces(which)
+    width = pieces[0][0].num_nodes
+    want = _host_stream(pieces)
+    blocks = list(interval_delta_blocks(pieces, block, capacity=8))
+    assert [b.rows for b in blocks] == [min(block, len(want) - lo)
+                                        for lo in range(0, len(want), block)]
+    if which == "burst" and block >= 10:
+        assert max(len(b.starts) for b in blocks) > 1
+    carry = np.zeros(width, np.int16)
+    host = []
+    for b in blocks:
+        out, carry = b.host_masks(width, carry)
+        host.append(out)
+    device = list(jax_backend.interval_masks_device(blocks, width, block))
+    assert np.array_equal(np.concatenate(host), want)
+    assert np.array_equal(np.concatenate(device), want)
+
+
+def test_interval_deltas_are_the_half_open_events():
+    """An event adds +1 on the row of its start and -1 on the row of its
+    end (``start <= t < end``); one that covers no row adds nothing, one
+    still active at the last sample ends on row ``len(ts)``."""
+    ev = [FaultEvent(0, 1.0, 3.0), FaultEvent(1, 2.2, 2.8),
+          FaultEvent(2, 2.5, 9.0), FaultEvent(3, 0.0, 1.0)]
+    row, node, step = FaultTrace(4, 10.0, ev).interval_deltas(
+        [0.0, 1.0, 2.0, 3.0])
+    got = sorted(zip(row.tolist(), node.tolist(), step.tolist()))
+    assert got == [(0, 3, 1), (1, 0, 1), (1, 3, -1), (3, 0, -1),
+                   (3, 2, 1), (4, 2, -1)]
+    assert np.all(np.diff(row) >= 0)
+    assert list(interval_delta_blocks([], 4)) == []
+    with pytest.raises(ValueError, match="positive"):
+        list(interval_delta_blocks([(FaultTrace(4, 10.0, ev), [0.0])], 0))
