@@ -11,28 +11,25 @@ seconds, bit-for-bit equal to the scalar event-by-event replay
 (``benchmarks/churn.py`` gates the >= 10x throughput claim).  For
 horizons or ensembles too large to concatenate, ``engine="streamed"``
 treats the realizations as one stream of intervals in blocks, with the
-same bit-for-bit grids in bounded memory (``tests/test_stream.py``): on
-the JAX backend each block goes to the device as its sorted occupancy
-deltas and its masks are built there (``evaluate_delta_stream``); on
-NumPy each realization's masks are built block by block on the host and
-re-chunked through ``evaluate_mask_stream``.
+same bit-for-bit grids in bounded memory (``tests/test_stream.py``): the
+stream is the mask source ``repro.sim.sources.IntervalDeltas``, each
+block its sorted occupancy deltas, whose masks are built on the device
+on the JAX backend and on the host on NumPy.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import (Callable, Dict, Iterator, List, Optional, Sequence,
-                    Tuple, Union)
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from .. import obs
-from ..core.trace import (DeltaBlock, FaultTrace, generate_trace,
-                          interval_delta_blocks, to_4gpu_trace)
+from ..core.trace import FaultTrace, generate_trace, to_4gpu_trace
 from ..obs.progress import Progress
-from ..sim.engine import (evaluate_delta_stream, evaluate_mask_stream,
-                          evaluate_masks, resolve_backend)
+from ..sim.engine import evaluate_masks, evaluate_source
 from ..sim.scenario import DEFAULT_ARCHITECTURES, make_model
+from ..sim.sources import IntervalDeltas
 from .replay import replay_trace
 from .timeline import ChurnTimeline
 
@@ -135,32 +132,6 @@ def _realizations(spec: ChurnSpec,
     return out
 
 
-def _mask_blocks(realizations: Sequence[Tuple[FaultTrace, np.ndarray]],
-                 rows: int) -> Iterator[np.ndarray]:
-    """Every realization's interval masks in turn, ``rows`` intervals per
-    block, each block built under a ``churn.masks`` span."""
-    for tr, edges in realizations:
-        blocks = tr.fault_mask_blocks(edges, rows)
-        for lo in range(0, len(edges), rows):
-            with obs.span("churn.masks", rows=min(rows, len(edges) - lo),
-                          nodes=tr.num_nodes):
-                masks = next(blocks)
-            yield masks
-
-
-def _delta_blocks(realizations: Sequence[Tuple[FaultTrace, np.ndarray]],
-                  rows: int, nodes: int) -> Iterator[DeltaBlock]:
-    """Every realization's interval masks in turn, as one stream of
-    occupancy-delta recipes of ``rows`` intervals each (the device builds
-    the masks), each sliced and padded under a ``churn.masks`` span."""
-    total = sum(len(edges) for _, edges in realizations)
-    blocks = interval_delta_blocks(realizations, rows)
-    for lo in range(0, total, rows):
-        with obs.span("churn.masks", rows=min(rows, total - lo), nodes=nodes):
-            block = next(blocks)
-        yield block
-
-
 def monte_carlo_replay(spec: ChurnSpec,
                        traces: Union[int, Sequence[FaultTrace]], *,
                        engine: str = "batched", backend: str = "auto",
@@ -176,17 +147,14 @@ def monte_carlo_replay(spec: ChurnSpec,
     pass; ``engine="streamed"`` produces bit-identical timelines in blocks
     of ``chunk_snapshots`` intervals of the realizations' joint stream,
     bounding peak mask memory at about one block whatever the horizon or
-    the ensemble size.  On the JAX backend each block's masks are built on
-    the device from its occupancy deltas
-    (``repro.core.trace.interval_delta_blocks``, ``evaluate_delta_stream``);
-    on NumPy each realization's masks are built on the host
-    (``FaultTrace.fault_mask_blocks``) and fed through
-    ``evaluate_mask_stream``, re-chunked across realization boundaries.
-    ``engine="scalar"`` loops the event-by-event reference replay.
+    the ensemble size: each block's masks are built from its occupancy
+    deltas (``repro.sim.sources.IntervalDeltas``), on the device on the
+    JAX backend and on the host on NumPy.  ``engine="scalar"`` loops the
+    event-by-event reference replay.
 
     ``progress`` (``engine="streamed"`` only) is forwarded to
-    ``evaluate_mask_stream`` -- one :class:`repro.obs.Progress` per
-    evaluated block; the default publishes ``sim.stream.*`` telemetry
+    ``repro.sim.engine.evaluate_source`` -- one :class:`repro.obs.Progress`
+    per evaluated block; the default publishes ``sim.stream.*`` telemetry
     gauges (blocks done, snapshots/sec, ETA).
     """
     if engine not in ("batched", "streamed", "scalar"):
@@ -206,21 +174,12 @@ def monte_carlo_replay(spec: ChurnSpec,
                   realizations=len(realizations)):
         intervals = int(sum(len(e) for _, e in realizations))
         if engine == "streamed":
-            chunk_snapshots = max(1, chunk_snapshots)
-            if resolve_backend(backend, models) == "jax":
-                width = (realizations[0][0].num_nodes if realizations
-                         else spec.num_nodes)
-                total, faulty, placed, chosen = evaluate_delta_stream(
-                    models, spec.tp_sizes, width,
-                    _delta_blocks(realizations, chunk_snapshots, width),
-                    intervals, chunk_snapshots=chunk_snapshots,
-                    progress=progress)
-            else:
-                total, faulty, placed, chosen = evaluate_mask_stream(
-                    models, spec.tp_sizes,
-                    _mask_blocks(realizations, chunk_snapshots), intervals,
-                    chunk_snapshots=chunk_snapshots, backend=backend,
-                    progress=progress)
+            width = (realizations[0][0].num_nodes if realizations
+                     else spec.num_nodes)
+            total, faulty, placed, chosen = evaluate_source(
+                models, spec.tp_sizes, IntervalDeltas(realizations, width),
+                intervals, chunk_snapshots=chunk_snapshots, backend=backend,
+                progress=progress)
         else:
             if realizations:
                 masks = np.concatenate([tr.fault_masks(e)

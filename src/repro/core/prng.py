@@ -177,7 +177,7 @@ def counter_fault_masks(num_nodes: int, node_fault_ratio: float,
     the JAX backend (on device, the same cipher over the same lanes) and
     the streaming engine (host, per chunk via ``start``) regenerate
     identical rows without ever materializing the full matrix (see
-    ``repro.sim.jax_backend.counter_masks_device``).
+    ``repro.sim.sources.MaskGen``).
 
     The whole batch is generated as vectorized broadcast cipher calls over
     bounded row blocks (keys from :func:`threefry_fold_in_batch`, lanes via

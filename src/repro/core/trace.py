@@ -190,6 +190,9 @@ class DeltaBlock:
     chunks: np.ndarray   # (k >= 1, 3, capacity) int32
     starts: np.ndarray   # (k,) int32
 
+    def __len__(self) -> int:
+        return self.rows
+
     @classmethod
     def pack(cls, rows: int, row: np.ndarray, node: np.ndarray,
              step: np.ndarray, capacity: int = DELTA_CAPACITY) -> "DeltaBlock":

@@ -11,7 +11,7 @@ Typical use (the engines already do this)::
 
     from repro import obs
 
-    with obs.span("sim.evaluate_masks", backend=backend, snapshots=n):
+    with obs.span("sim.evaluate_source", backend=backend, snapshots=n):
         ...
         obs.count("sim.snapshots_evaluated", n)
         obs.gauge("prng.rss_mb", obs.rss_mb())
@@ -28,13 +28,15 @@ beside the device planes (:mod:`repro.obs.telemetry`).
 Spans of the path from a spec to its tables, outermost first:
 
   * sweep (``repro.sim``): ``sim.models`` (building the architecture
-    models), ``sim.run_sweep``, ``prng.counter_fault_masks`` (host mask
-    draw), ``sim.stream.block`` / ``sim.evaluate_masks``,
-    ``sim.jax.setup`` (evaluator and totals, once per ``sweep_grids``
-    call or mask stream), ``sim.jax.eval_block``
-    holding ``sim.jax.put`` (host to device) and ``sim.jax.fetch`` (device
-    to host, int64 unpacking), then ``sim.tables.waste_table`` /
-    ``sim.tables.max_job_table``;
+    models), ``sim.run_sweep``, ``sim.evaluate_source`` around the
+    engine's one loop over a mask source, ``sim.jax.setup`` (evaluator and
+    totals, once per stream), one ``sim.stream.block`` per block holding
+    the NumPy kernels (``prng.counter_fault_masks``, the host mask draw,
+    then ``sim.numpy.eval_chunk``) or ``sim.jax.eval_block``, which holds
+    ``sim.jax.put`` (host to device), the source's device build
+    (``prng.device_masks``, counter ``prng.device_masks_drawn``) and
+    ``sim.jax.fetch`` (device to host, int64 unpacking), then
+    ``sim.tables.waste_table`` / ``sim.tables.max_job_table``;
   * DCN (``repro.dcn``): ``dcn.run_dcn_sweep``, ``dcn.evaluate_placements``
     per variant and TP (the orchestrated one holding ``dcn.jax.put`` /
     ``dcn.jax.fetch`` per block), ``dcn.pair_counts`` per variant and TP,
@@ -42,10 +44,11 @@ Spans of the path from a spec to its tables, outermost first:
   * churn (``repro.churn``): ``churn.trace`` per realization (generation,
     4-GPU split and interval edges; counters ``churn.fault_events`` and
     ``churn.intervals``), ``churn.monte_carlo_replay`` holding the
-    streamed evaluation with one ``churn.masks`` per block of interval
-    masks (on the JAX backend: slicing the block's occupancy deltas, whose
-    masks are built on the device under ``churn.device_masks`` inside the
-    block's ``sim.jax.eval_block``; counter ``churn.device_masks_built``),
+    streamed evaluation (the loop above over the interval source) with
+    one ``churn.masks`` per block slicing its occupancy deltas, whose
+    masks are built on the host on NumPy and on the device under
+    ``churn.device_masks`` inside the block's ``sim.jax.eval_block`` on
+    JAX (counter ``churn.device_masks_built``),
     then ``churn.tables`` around the timeline slicing and each
     ``integrated_waste_table`` / ``ChurnEnsemble.summary_table``.
 """

@@ -2,9 +2,10 @@
 
 A :class:`StreamProgress` tracks one bounded stream (total units known up
 front, e.g. snapshots) and produces :class:`Progress` updates carrying
-blocks done, units/sec throughput, and an ETA.  The streaming engines
-(``repro.sim.evaluate_mask_stream``, ``monte_carlo_replay``
-``engine="streamed"``) drive one per run and hand each update to a
+blocks done, units/sec throughput, and an ETA.  The engine's streaming
+loop (``repro.sim.engine.evaluate_source``, behind
+``evaluate_mask_stream`` and ``monte_carlo_replay`` ``engine="streamed"``)
+drives one per run and hands each update to a
 ``progress`` callback -- by default :func:`telemetry_progress`, which
 publishes the update as telemetry gauges (``<prefix>.blocks_done``,
 ``<prefix>.units_per_sec``, ``<prefix>.eta_s``), so a multi-minute

@@ -1,24 +1,29 @@
-"""Batched sweep runner: evaluate a ScenarioSpec grid in vectorized chunks.
+"""Batched sweep runner: evaluate a ScenarioSpec grid block by block.
 
 Reproduces the paper's §6.2 fault-resiliency figures (Figs. 13-16: waste
 ratio, max job scale, fault-waiting share) at grid scale; the churn
 (Fig. 18), traffic (Fig. 17) and cost (§6.5) engines all consume the
 grids it produces.
 
-The engine materializes the snapshot fault-mask matrix once, then runs every
-architecture's vectorized ``evaluate_batch`` kernel over it, chunking the
-snapshot axis so datacenter-scale sweeps (100k nodes x thousands of
-snapshots) stay within a bounded memory footprint.  Results land in a dense
-``(architectures, snapshots, tp_sizes)`` grid that the table helpers reduce
-to the paper's figures.
+Every evaluation is one loop, :func:`evaluate_source`, over a mask source
+(``repro.sim.sources``): host bool matrices (:func:`evaluate_masks`,
+:func:`evaluate_mask_stream`), the counter i.i.d. stream of a
+``CounterIIDSnapshots`` spec, or a churn stream's interval deltas.  The
+loop runs every architecture's kernel over each block of the source's
+snapshot axis, so datacenter-scale sweeps (100k nodes x millions of
+snapshots) stay within a bounded memory footprint, and fills a dense
+``(architectures, snapshots, tp_sizes)`` grid that the table helpers
+reduce to the paper's figures.
 
 Two compute backends produce that grid bit-for-bit identically:
 
-  * ``backend="numpy"`` -- the vectorized host kernels on each model;
-  * ``backend="jax"``   -- ``repro.sim.jax_backend``: the same kernels as
-    pure ``jax.numpy`` functions under ``jax.vmap``/``jax.jit`` with the
-    snapshot axis sharded across devices (million-snapshot sweeps); a
-    ``CounterIIDSnapshots`` spec has its masks drawn on the device too.
+  * ``backend="numpy"`` -- each block's host masks through the vectorized
+    ``evaluate_batch`` kernel of each model;
+  * ``backend="jax"``   -- ``repro.sim.jax_backend``: one
+    ``GridEvaluator`` per stream runs the same kernels as pure
+    ``jax.numpy`` functions under ``jax.vmap``/``jax.jit`` with the
+    snapshot axis sharded across devices; the source's device half builds
+    each block's masks on the device where it can.
 
 ``backend="auto"`` (the default) picks JAX whenever it is importable and
 every requested architecture has a jnp kernel; the ``REPRO_SWEEP_BACKEND``
@@ -36,10 +41,9 @@ import numpy as np
 
 from .. import obs
 from ..core.hbd_models import HBDModel
-from ..core.prng import counter_fault_masks
-from ..core.trace import DeltaBlock
 from ..obs.progress import Progress, StreamProgress
 from .scenario import CounterIIDSnapshots, ScenarioSpec
+from .sources import HostChunks, MaskGen
 
 BACKENDS = ("numpy", "jax")
 
@@ -118,71 +122,26 @@ class SweepResult:
         return int(np.nonzero(self.tp_sizes == tp)[0][0])
 
 
-def evaluate_masks(models: Sequence[HBDModel], tp_sizes: Sequence[int],
-                   masks: np.ndarray, *, chunk_snapshots: int = 1024,
-                   backend: str = "auto") -> Tuple[np.ndarray, np.ndarray,
-                                                   np.ndarray, str]:
-    """Evaluate a pre-materialized ``(snapshots, nodes)`` mask matrix.
+Grids = Tuple[np.ndarray, np.ndarray, np.ndarray, str]
 
-    The mask-in/grids-out core shared by :func:`run_sweep` and the churn
-    replay engine (``repro.churn``): every model's batched kernel over every
-    snapshot x TP cell, chunked along the snapshot axis.  Returns int64
+
+def evaluate_source(models: Sequence[HBDModel], tp_sizes: Sequence[int],
+                    source, total_snapshots: int, *,
+                    chunk_snapshots: int = 1024, backend: str = "auto",
+                    progress: Optional[Callable[[Progress], None]] = None
+                    ) -> Grids:
+    """Evaluate every block of a mask ``source`` (``repro.sim.sources``)
+    whose rows add up to ``total_snapshots``.
+
+    The source cuts its stream into blocks of ``chunk_snapshots`` rows
+    (only the last may be shorter).  On NumPy each block's host masks go
+    through every model's batched kernel; on JAX one
+    ``repro.sim.jax_backend.GridEvaluator``, made at the first block under
+    ``sim.jax.setup``, takes every block, builds its masks where the
+    source's device half says and pads the short last block up to the
+    others, so the stream runs one compiled shape.  Returns int64
     ``(total (A, T), faulty (A, S, T), placed (A, S, T), backend)`` grids,
-    bit-for-bit identical across backends.
-    """
-    chosen = resolve_backend(backend, models)
-    masks = np.asarray(masks, dtype=bool)
-    tp_sizes = list(tp_sizes)
-
-    with obs.span("sim.evaluate_masks", backend=chosen,
-                  snapshots=masks.shape[0], models=len(models)):
-        obs.count("sim.snapshots_evaluated", masks.shape[0])
-        if chosen == "jax":
-            from . import jax_backend
-            total, faulty, placed = jax_backend.sweep_grids(
-                models, tp_sizes, masks=masks,
-                chunk_snapshots=chunk_snapshots)
-            return total, faulty, placed, "jax"
-
-        snaps = masks.shape[0]
-        tcount = len(tp_sizes)
-        total = np.zeros((len(models), tcount), dtype=np.int64)
-        faulty = np.zeros((len(models), snaps, tcount), dtype=np.int64)
-        placed = np.zeros((len(models), snaps, tcount), dtype=np.int64)
-        chunk_snapshots = max(1, chunk_snapshots)  # same clamp as the jax path
-        for lo in range(0, max(snaps, 1), chunk_snapshots):
-            chunk = masks[lo:lo + chunk_snapshots]
-            if not chunk.shape[0]:
-                break
-            with obs.span("sim.numpy.eval_chunk", rows=chunk.shape[0]):
-                for ai, model in enumerate(models):
-                    grid = model.evaluate_batch(chunk, tp_sizes)
-                    total[ai] = grid.total_gpus
-                    faulty[ai, lo:lo + chunk.shape[0]] = grid.faulty_gpus
-                    placed[ai, lo:lo + chunk.shape[0]] = grid.placed_gpus
-    return total, faulty, placed, "numpy"
-
-
-def evaluate_mask_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
-                         chunks: Iterable[np.ndarray], total_snapshots: int,
-                         *, chunk_snapshots: int = 1024,
-                         backend: str = "auto",
-                         progress: Optional[Callable[[Progress], None]] = None
-                         ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, str]:
-    """Evaluate a *stream* of mask chunks in bounded memory.
-
-    ``chunks`` is any iterable of ``(rows_i, nodes)`` bool matrices whose
-    rows concatenate to ``total_snapshots`` snapshots.  Incoming chunks are
-    re-chunked into evaluation blocks of exactly ``chunk_snapshots`` rows
-    (chunk boundaries in the source need not align with evaluation
-    boundaries; only the stream's last block may be shorter), so the grids
-    are bit-for-bit equal to one :func:`evaluate_masks` call on the full
-    concatenation while peak mask memory stays at about one block plus the
-    largest single source chunk -- a million-snapshot x 10k-node stream
-    never exists as a 10 GB host matrix.  On the JAX backend every block
-    flows through one ``repro.sim.jax_backend.GridEvaluator`` sized to the
-    block, which pads the short last block up to it: however the stream's
-    length falls, it runs one compiled shape, with donated device buffers.
+    bit-for-bit identical across backends and block sizes.
 
     ``progress`` is called once per evaluated block with a
     :class:`repro.obs.Progress` (blocks done, snapshots/sec, ETA); the
@@ -196,110 +155,73 @@ def evaluate_mask_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
     total = np.zeros((a_count, t_count), dtype=np.int64)
     faulty = np.zeros((a_count, total_snapshots, t_count), dtype=np.int64)
     placed = np.zeros((a_count, total_snapshots, t_count), dtype=np.int64)
-    chunk_snapshots = max(1, chunk_snapshots)
-    state = {"lo": 0, "evaluator": None}
-    pending: List[np.ndarray] = []
+    rows = max(1, chunk_snapshots)
     tracker = StreamProgress(total_snapshots, progress, prefix="sim.stream")
-
-    def evaluate(block: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        if chosen != "jax":
-            t, f, p, _ = evaluate_masks(models, tp_sizes, block,
-                                        chunk_snapshots=chunk_snapshots,
-                                        backend=chosen)
-            total[:] = t
-            return f, p
-        if state["evaluator"] is None:
-            from . import jax_backend
-            with obs.span("sim.jax.setup", rows=total_snapshots):
-                state["evaluator"] = jax_backend.GridEvaluator(
-                    models, tp_sizes, block.shape[1],
-                    rows=min(chunk_snapshots, total_snapshots))
-                total[:] = state["evaluator"].totals()
-        obs.count("sim.snapshots_evaluated", block.shape[0])
-        return state["evaluator"].eval_block(block)
-
-    def flush(block: np.ndarray) -> None:
-        lo = state["lo"]
-        with obs.span("sim.stream.block", rows=block.shape[0], offset=lo,
-                      backend=chosen):
-            f, p = evaluate(block)
-        faulty[:, lo:lo + block.shape[0]] = f
-        placed[:, lo:lo + block.shape[0]] = p
-        state["lo"] = lo + block.shape[0]
-        tracker.update(block.shape[0])
-
-    with obs.span("sim.evaluate_mask_stream", backend=chosen,
-                  snapshots=total_snapshots):
-        for chunk in chunks:
-            chunk = np.asarray(chunk, dtype=bool)
-            if not chunk.shape[0]:
-                continue
-            pending.append(chunk)
-            if sum(c.shape[0] for c in pending) < chunk_snapshots:
-                continue
-            rows = pending[0] if len(pending) == 1 else np.concatenate(pending)
-            del pending[:]
-            full = rows.shape[0] - rows.shape[0] % chunk_snapshots
-            for lo in range(0, full, chunk_snapshots):
-                flush(rows[lo:lo + chunk_snapshots])
-            if full < rows.shape[0]:        # a copy frees the source
-                pending.append(rows[full:].copy())
-        if pending:
-            flush(pending[0] if len(pending) == 1 else np.concatenate(pending))
-    if state["lo"] != total_snapshots:
-        raise ValueError(f"mask stream yielded {state['lo']} snapshots, "
+    evaluator, lo = None, 0
+    with obs.span("sim.evaluate_source", backend=chosen,
+                  snapshots=total_snapshots, models=a_count):
+        for block in source.blocks(rows):
+            n = len(block)
+            if chosen == "jax" and evaluator is None:
+                from . import jax_backend
+                with obs.span("sim.jax.setup", rows=total_snapshots):
+                    evaluator = jax_backend.GridEvaluator(
+                        models, tp_sizes, source.width, source=source,
+                        rows=min(rows, total_snapshots))
+                    total[:] = evaluator.totals()
+            with obs.span("sim.stream.block", rows=n, offset=lo,
+                          backend=chosen):
+                obs.count("sim.snapshots_evaluated", n)
+                if evaluator is not None:
+                    f, p = evaluator.eval_block(block)
+                    faulty[:, lo:lo + n], placed[:, lo:lo + n] = f, p
+                else:
+                    masks = source.host_masks(block)
+                    with obs.span("sim.numpy.eval_chunk", rows=n):
+                        for ai, model in enumerate(models):
+                            grid = model.evaluate_batch(masks, tp_sizes)
+                            total[ai] = grid.total_gpus
+                            faulty[ai, lo:lo + n] = grid.faulty_gpus
+                            placed[ai, lo:lo + n] = grid.placed_gpus
+            lo += n
+            tracker.update(n)
+    if lo != total_snapshots:
+        raise ValueError(f"mask stream yielded {lo} snapshots, "
                          f"expected {total_snapshots}")
     return total, faulty, placed, chosen
 
 
-def evaluate_delta_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
-                          width: int, blocks: Iterable[DeltaBlock],
-                          total_snapshots: int, *, chunk_snapshots: int = 1024,
-                          progress: Optional[Callable[[Progress], None]] = None
-                          ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, str]:
-    """:func:`evaluate_mask_stream` for an interval stream whose masks are
-    built on the device (JAX backend only).
+def evaluate_masks(models: Sequence[HBDModel], tp_sizes: Sequence[int],
+                   masks: np.ndarray, *, chunk_snapshots: int = 1024,
+                   backend: str = "auto") -> Grids:
+    """Evaluate a pre-materialized ``(snapshots, nodes)`` mask matrix: the
+    :func:`evaluate_source` of one host chunk, shared by :func:`run_sweep`
+    and the churn replay engine (``repro.churn``)."""
+    masks = np.asarray(masks, dtype=bool)
+    return evaluate_source(models, tp_sizes, HostChunks([masks]),
+                           masks.shape[0], chunk_snapshots=chunk_snapshots,
+                           backend=backend)
 
-    ``blocks`` are the stream's blocks of ``chunk_snapshots`` rows (the
-    last may be shorter) as occupancy-delta recipes
-    (``repro.core.trace.interval_delta_blocks``) over ``width`` nodes.
-    They flow through one ``repro.sim.jax_backend.GridEvaluator``, which
-    carries every node's count on the device from block to block and pads
-    the short last block, so the stream runs one compiled shape.  The
-    grids, and ``progress``, are those of :func:`evaluate_mask_stream` on
-    the same masks.
+
+def evaluate_mask_stream(models: Sequence[HBDModel], tp_sizes: Sequence[int],
+                         chunks: Iterable[np.ndarray], total_snapshots: int,
+                         *, chunk_snapshots: int = 1024,
+                         backend: str = "auto",
+                         progress: Optional[Callable[[Progress], None]] = None
+                         ) -> Grids:
+    """Evaluate a *stream* of mask chunks in bounded memory.
+
+    ``chunks`` is any iterable of ``(rows_i, nodes)`` bool matrices whose
+    rows concatenate to ``total_snapshots`` snapshots, re-cut into blocks
+    of ``chunk_snapshots`` rows (``repro.sim.sources.HostChunks``), so the
+    grids are bit-for-bit equal to one :func:`evaluate_masks` call on the
+    full concatenation while a million-snapshot x 10k-node stream never
+    exists as a 10 GB host matrix.  ``progress`` is that of
+    :func:`evaluate_source`.
     """
-    from . import jax_backend
-    tp_sizes = list(tp_sizes)
-    a_count, t_count = len(models), len(tp_sizes)
-    total = np.zeros((a_count, t_count), dtype=np.int64)
-    faulty = np.zeros((a_count, total_snapshots, t_count), dtype=np.int64)
-    placed = np.zeros((a_count, total_snapshots, t_count), dtype=np.int64)
-    chunk_snapshots = max(1, chunk_snapshots)
-    tracker = StreamProgress(total_snapshots, progress, prefix="sim.stream")
-    evaluator = None
-    lo = 0
-    with obs.span("sim.evaluate_delta_stream", backend="jax",
-                  snapshots=total_snapshots):
-        for block in blocks:
-            if evaluator is None:
-                with obs.span("sim.jax.setup", rows=total_snapshots):
-                    evaluator = jax_backend.GridEvaluator(
-                        models, tp_sizes, width,
-                        rows=min(chunk_snapshots, total_snapshots))
-                    total[:] = evaluator.totals()
-            with obs.span("sim.stream.block", rows=block.rows, offset=lo,
-                          backend="jax"):
-                obs.count("sim.snapshots_evaluated", block.rows)
-                f, p = evaluator.eval_deltas(block)
-            faulty[:, lo:lo + block.rows] = f
-            placed[:, lo:lo + block.rows] = p
-            lo += block.rows
-            tracker.update(block.rows)
-    if lo != total_snapshots:
-        raise ValueError(f"delta stream yielded {lo} snapshots, "
-                         f"expected {total_snapshots}")
-    return total, faulty, placed, "jax"
+    return evaluate_source(models, tp_sizes, HostChunks(chunks),
+                           total_snapshots, chunk_snapshots=chunk_snapshots,
+                           backend=backend, progress=progress)
 
 
 def run_sweep(spec: ScenarioSpec, *, masks: Optional[np.ndarray] = None,
@@ -310,8 +232,12 @@ def run_sweep(spec: ScenarioSpec, *, masks: Optional[np.ndarray] = None,
 
     ``masks``/``models`` may be supplied to reuse an already-materialized
     snapshot matrix or model instances (the benchmarks do both so timing
-    isolates the kernels).  ``backend`` selects the compute path (see the
-    module docstring); the grids are bit-for-bit identical either way.
+    isolates the kernels).  Without ``masks``, a ``CounterIIDSnapshots``
+    spec streams its masks block by block (``repro.sim.sources.MaskGen``:
+    drawn on the device on JAX, regenerated per block from its start
+    offset on NumPy), so a million-snapshot spec never materializes the
+    full matrix.  ``backend`` selects the compute path (see the module
+    docstring); the grids are bit-for-bit identical either way.
     """
     if models is None:
         with obs.span("sim.models") as sp:
@@ -323,44 +249,18 @@ def run_sweep(spec: ScenarioSpec, *, masks: Optional[np.ndarray] = None,
 
     with obs.span("sim.run_sweep", backend=chosen, nodes=spec.num_nodes,
                   models=len(models)):
-        if chosen == "jax" and masks is None \
-                and isinstance(spec.snapshots, CounterIIDSnapshots):
-            # counter-based spec: each block's masks are drawn on the
-            # device (bit-identical to the host stream) and never leave it
-            from . import jax_backend
-            sn = spec.snapshots
-            obs.count("sim.snapshots_evaluated", sn.samples)
-            gen = jax_backend.MaskGen(sn.samples, spec.num_nodes,
-                                      sn.fault_ratio, sn.seed)
-            total, faulty, placed = jax_backend.sweep_grids(
-                models, spec.tp_sizes, gen=gen,
-                chunk_snapshots=chunk_snapshots)
-            return SweepResult(spec, names, tps, total, faulty, placed,
-                               backend="jax")
-
-        if masks is None:
-            if isinstance(spec.snapshots, CounterIIDSnapshots):
-                # counter streams regenerate any row range bit-identically
-                # from a start offset, so stream the masks chunk by chunk --
-                # a million-snapshot spec never materializes the full host
-                # matrix
-                sn = spec.snapshots
-                step = max(1, chunk_snapshots)
-                chunks = (counter_fault_masks(spec.num_nodes, sn.fault_ratio,
-                                              min(step, sn.samples - off),
-                                              sn.seed, start=off)
-                          for off in range(0, sn.samples, step))
-                total, faulty, placed, chosen = evaluate_mask_stream(
-                    models, spec.tp_sizes, chunks, sn.samples,
-                    chunk_snapshots=chunk_snapshots, backend=chosen)
-                return SweepResult(spec, names, tps, total, faulty, placed,
-                                   backend=chosen)
-            masks = spec.snapshots.masks(spec.num_nodes)
-        total, faulty, placed, chosen = evaluate_masks(
-            models, spec.tp_sizes, masks, chunk_snapshots=chunk_snapshots,
-            backend=chosen)
-        return SweepResult(spec, names, tps, total, faulty, placed,
-                           backend=chosen)
+        sn = spec.snapshots
+        if masks is None and isinstance(sn, CounterIIDSnapshots):
+            grids = evaluate_source(
+                models, spec.tp_sizes,
+                MaskGen(sn.samples, spec.num_nodes, sn.fault_ratio, sn.seed),
+                sn.samples, chunk_snapshots=chunk_snapshots, backend=chosen)
+        else:
+            grids = evaluate_masks(
+                models, spec.tp_sizes,
+                sn.masks(spec.num_nodes) if masks is None else masks,
+                chunk_snapshots=chunk_snapshots, backend=chosen)
+        return SweepResult(spec, names, tps, *grids)
 
 
 def run_sweep_scalar(spec: ScenarioSpec, *,

@@ -6,36 +6,40 @@ over the snapshot axis and ``jax.jit`` over the whole (architectures x
 snapshots x TP sizes) grid.  When the engine has several devices
 (``repro.runtime.engine_devices``) the snapshot axis is sharded across them
 with ``jax.shard_map``, so million-snapshot sweeps scale with the device
-count.  Chunks are device-resident and their input buffers donated,
-keeping peak memory at ~one chunk regardless of sweep size.
+count.  :class:`GridEvaluator` runs one stream block by block, its blocks
+device-resident and their input buffers donated, keeping peak memory at
+~one block regardless of sweep size.
+
+Each mask source of ``repro.sim.sources`` has its device half here, which
+copies what the device needs of a block and builds its masks there:
+
+  * :class:`HostMaskCopy` -- host masks, copied as they are;
+  * :class:`CounterDraw` -- counter i.i.d. masks, each block's rows hashed
+    by the repository's jnp threefry-2x32 (``repro.faults.jax_mirror``)
+    over the explicit counter lanes of ``repro.core.prng.counter_lanes``
+    (:func:`_draw_fn`), bit-identical to the host's
+    ``repro.core.prng.counter_fault_masks`` whatever
+    ``jax_threefry_partitionable`` says;
+  * :class:`IntervalMaskSource` -- interval masks of a churn stream, built
+    from the sorted occupancy deltas of
+    ``repro.core.trace.interval_delta_blocks`` (:func:`_interval_fn`), the
+    per-node counts carried on the device from block to block,
+    bit-identical to ``FaultTrace.fault_mask_blocks``.
+
+The built block goes into the grid program without leaving the device.
 
 Guarantees (enforced by ``tests/test_jax_backend.py``):
 
   * bit-for-bit equality with the NumPy engine -- kernels compute in int32
     on device (all grid quantities fit comfortably) and are widened to the
     engine's int64 grids on the host;
-  * deterministic results independent of chunking and device count;
-  * counter i.i.d. fault masks are drawn on the device: each block's rows
-    are hashed by the repository's jnp threefry-2x32
-    (``repro.faults.jax_mirror``) over the explicit counter lanes of
-    ``repro.core.prng.counter_lanes``, bit-identical to the host's
-    ``repro.core.prng.counter_fault_masks`` whatever
-    ``jax_threefry_partitionable`` says, and the block goes from the draw
-    into the grid program without leaving the device;
-  * interval masks of a churn stream are built on the device from the
-    sorted occupancy deltas of ``repro.core.trace.interval_delta_blocks``
-    (``_interval_fn``), the per-node counts carried on the device from
-    block to block, bit-identical to ``FaultTrace.fault_mask_blocks``.
-    Every other mask source is drawn on the host and copied over block by
-    block.
+  * deterministic results independent of block size and device count.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import warnings
-from typing import (Callable, Dict, Iterable, Iterator, Optional, Sequence,
-                    Tuple, Type)
+from typing import Callable, Dict, Iterator, Optional, Sequence, Tuple, Type
 
 import numpy as np
 
@@ -59,18 +63,6 @@ from ..core.hbd_models import (BigSwitch, HBDModel, InfiniteHBDModel,
                                NVLModel, SiPRingModel, TPUv4Model)
 
 _SNAP_AXIS = "snap"
-
-
-@dataclasses.dataclass(frozen=True)
-class MaskGen:
-    """A counter i.i.d. mask source drawn on the device (no host matrix):
-    rows ``0..samples-1`` of ``counter_fault_masks(num_nodes, fault_ratio,
-    samples, seed)``."""
-
-    samples: int
-    num_nodes: int
-    fault_ratio: float
-    seed: int
 
 
 # ---------------------------------------------------------------- kernels
@@ -410,9 +402,62 @@ def _zero_snapshot_totals(models: Sequence[HBDModel],
         for m in models])
 
 
+def _pad(block: np.ndarray, rows: int) -> np.ndarray:
+    """``block`` with zero rows appended up to ``rows`` (the tail block of
+    a stream only)."""
+    if block.shape[0] == rows:
+        return block
+    return np.concatenate([block, np.zeros((rows - block.shape[0],)
+                                           + block.shape[1:], block.dtype)])
+
+
+class HostMaskCopy:
+    """Device half of ``repro.sim.sources.HostChunks``: each block's host
+    masks, padded and copied to the devices as they are."""
+
+    def __init__(self, mesh):
+        self.sharding = (None if mesh is None
+                         else NamedSharding(mesh, P(_SNAP_AXIS)))
+
+    def put(self, block: np.ndarray, rows: int) -> Tuple[object, int]:
+        block = _pad(block, rows)
+        # one transfer straight into the sharded layout (device_put from
+        # host numpy) -- no intermediate full copy on the default device
+        return (jnp.asarray(block) if self.sharding is None
+                else jax.device_put(block, self.sharding)), block.nbytes
+
+    def build(self, block: np.ndarray, arg):
+        return arg
+
+
+class CounterDraw(HostMaskCopy):
+    """Device half of ``repro.sim.sources.MaskGen``: each row's snapshot
+    index folded into its threefry key on the host (8 B a row), the masks
+    drawn on the device by :func:`_draw_fn`."""
+
+    def __init__(self, gen, width: int, mesh):
+        super().__init__(mesh)
+        self.width = width
+        self.draw = _draw_fn(width, mesh)
+        self.root = cprng.threefry_seed(gen.seed)
+        self.thresh = _threshold_args(gen.fault_ratio)
+
+    def put(self, block: np.ndarray, rows: int) -> Tuple[object, int]:
+        return super().put(
+            cprng.threefry_fold_in_batch(self.root, _pad(block, rows)), rows)
+
+    def build(self, block: np.ndarray, keys):
+        with obs.span("prng.device_masks", samples=len(block),
+                      nodes=self.width):
+            masks = self.draw(keys, *self.thresh)
+        obs.count("prng.device_masks_drawn", len(block))
+        return masks
+
+
 class IntervalMaskSource:
-    """Builds the interval masks of one stream on the device, block by
-    block, from ``repro.core.trace.DeltaBlock`` recipes.
+    """Device half of ``repro.sim.sources.IntervalDeltas``: builds the
+    interval masks of one stream on the device, block by block, from
+    ``repro.core.trace.DeltaBlock`` recipes.
 
     Every node's count is carried on the device from block to block,
     starting at zero, so nothing per node crosses the host link.  Each
@@ -427,6 +472,7 @@ class IntervalMaskSource:
         if rows % ndev:
             raise ValueError(f"{rows} rows do not split over {ndev} devices")
         self.rows = rows
+        self.width = width
         self.replicated = None if mesh is None else NamedSharding(mesh, P())
         self.fn = _interval_fn(rows, width, mesh)
         self.blank = jnp.zeros(
@@ -435,224 +481,112 @@ class IntervalMaskSource:
                                                            P(_SNAP_AXIS)))
         self.carry = jnp.zeros((width,), jnp.int32, device=self.replicated)
 
-    def put(self, block: DeltaBlock) -> list:
+    def put(self, block: DeltaBlock, rows: int) -> Tuple[list, int]:
         """The block's chunks of deltas, copied to every device."""
         if block.rows > self.rows:
             raise ValueError(f"delta block of {block.rows} rows exceeds "
                              f"the source's {self.rows}")
-        return [jax.device_put(c, self.replicated) for c in block.chunks]
+        return ([jax.device_put(c, self.replicated) for c in block.chunks],
+                block.chunks.nbytes)
 
     def build(self, block: DeltaBlock, chunks: list):
         """Dispatch the build of ``block`` from its :meth:`put` chunks; the
         counts carried out are kept for the next block."""
-        out = self.blank
-        for start, deltas in zip(block.starts, chunks):
-            out, self.carry = self.fn(out, self.carry, start, deltas)
+        with obs.span("churn.device_masks", rows=block.rows,
+                      nodes=self.width):
+            out = self.blank
+            for start, deltas in zip(block.starts, chunks):
+                out, self.carry = self.fn(out, self.carry, start, deltas)
+        obs.count("churn.device_masks_built", block.rows)
         return out
 
 
 class GridEvaluator:
-    """Reusable device grid evaluator bound to one ``(models, tps, width)``.
+    """Reusable device grid evaluator bound to one ``(models, tps, width)``
+    and one mask source (``repro.sim.sources``).
 
-    Holds the mesh, sharding, jitted grid function and zero-snapshot totals
-    so a *streaming* caller can push chunk after chunk through one compiled
-    executable with donated input buffers -- device memory stays at ~one
-    chunk no matter how many snapshots flow through.  :func:`sweep_grids`
-    is a loop over :meth:`eval_block`; ``repro.sim.engine``'s
-    ``evaluate_mask_stream`` drives one evaluator across an entire mask
-    stream (million-snapshot Monte-Carlo) without ever materializing the
-    full matrix on host or device.  ``rows`` is the caller's block size:
-    shorter blocks are padded up to it.  A block comes in one of three
-    kinds: a host bool mask matrix or a ``MaskGen`` index vector
-    (:meth:`eval_block`), or a ``repro.core.trace.DeltaBlock`` of an
-    interval stream, built into masks on the device
-    (:meth:`eval_deltas`, ``repro.sim.engine.evaluate_delta_stream``).
+    Holds the mesh, the jitted grid function, the source's device half and
+    the zero-snapshot totals, so the engine's one loop
+    (``repro.sim.engine.evaluate_source``) pushes block after block of a
+    stream through one compiled executable with donated input buffers --
+    device memory stays at ~one block no matter how many snapshots flow
+    through, and the full mask matrix never exists on host or device.
+    ``rows`` is the caller's block size: shorter blocks are padded up to
+    it.
     """
 
     def __init__(self, models: Sequence[HBDModel], tps: Sequence[int],
-                 width: int, gen: Optional[MaskGen] = None, rows: int = 1):
+                 width: int, *, source, rows: int = 1):
         require(models)
         self.models = list(models)
         self.tps = [int(t) for t in tps]
-        self.width = width
-        self.gen = gen
         self.mesh = snapshot_mesh(_SNAP_AXIS)
         self.ndev = 1 if self.mesh is None else self.mesh.devices.size
         # the block shape every shorter block is padded to: a stream whose
         # last block is short runs the executable its full blocks compiled
         self.rows = -(-max(1, rows) // self.ndev) * self.ndev
-        self.sharding = (None if self.mesh is None
-                         else NamedSharding(self.mesh, P(_SNAP_AXIS)))
         self.fn = _grid_fn(self.models, self.tps, self.mesh, width)
-        self.intervals = None            # eval_deltas' interval-mask source
-        if gen is not None:
-            self.draw = _draw_fn(width, self.mesh)
-            self.root = cprng.threefry_seed(gen.seed)
-            self.thresh = _threshold_args(gen.fault_ratio)
+        self.device_source = source.device(self.rows, width, self.mesh)
 
     def totals(self) -> np.ndarray:
         """Per-model (A, T) ``total_gpus`` grid (NumPy-engine identical)."""
         return _zero_snapshot_totals(self.models, self.tps)
 
-    def eval_block(self, block: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
-        """Evaluate one block; returns int64 ``(faulty, placed)``, each
-        ``(A, rows, T)``.
+    def eval_block(self, block) -> Tuple[np.ndarray, np.ndarray]:
+        """Evaluate one block of the source; returns int64 ``(faulty,
+        placed)``, each ``(A, len(block), T)``.
 
-        ``block`` is a ``(rows, width)`` bool mask matrix -- or, when the
-        evaluator was built with ``gen``, a ``(rows,)`` integer vector of
-        counter-stream snapshot indices, whose masks are drawn on the
-        device.  Rows are padded on the tail to the evaluator's ``rows``
-        (a block longer than that to a device-count multiple) and the pad
-        rows discarded, so every block of up to ``rows`` runs one compiled
+        Rows are padded on the tail to the evaluator's ``rows`` (a block
+        longer than that to a device-count multiple) and the pad rows
+        discarded, so every block of up to ``rows`` runs one compiled
         shape.
 
         Spans: ``sim.jax.eval_block`` around the whole block, inside it
-        ``sim.jax.put`` (padding and the copy to the device: the mask
-        block, or each row's threefry key folded on the host),
-        ``prng.device_masks`` (the dispatch of the device draw, ``gen``
-        only) and ``sim.jax.fetch`` (the copy back and the int64
+        ``sim.jax.put`` (padding and the copy to the device of what the
+        source's device half needs: the mask block, each row's folded
+        threefry key, or the deltas), the source's build span
+        (``prng.device_masks``, ``churn.device_masks``; none for host
+        masks) and ``sim.jax.fetch`` (the copy back and the int64
         unpacking, after the program has finished).  The rest of
         ``eval_block`` is the wait for the transfer tail and the programs.
         The method takes no timings of its own.
         """
-        rows = block.shape[0]
+        rows = len(block)
         with obs.span("sim.jax.eval_block", rows=rows, devices=self.ndev):
             with obs.span("sim.jax.put", rows=rows) as sp:
-                padded = max(self.rows, -(-rows // self.ndev) * self.ndev)
-                if padded != rows:             # pad the tail chunk only
-                    block = np.concatenate(
-                        [block, np.zeros((padded - rows,) + block.shape[1:],
-                                         block.dtype)])
-                if self.gen is not None:
-                    block = cprng.threefry_fold_in_batch(self.root, block)
-                sp.set(bytes=block.nbytes)
-                # one transfer straight into the sharded layout (device_put
-                # from host numpy) -- no intermediate full copy on the
-                # default device
-                arg = (jnp.asarray(block) if self.sharding is None
-                       else jax.device_put(block, self.sharding))
-            if self.gen is not None:
-                with obs.span("prng.device_masks", samples=rows,
-                              nodes=self.width):
-                    arg = self.draw(arg, *self.thresh)
-                obs.count("prng.device_masks_drawn", rows)
-            return self._grids(arg, rows)
-
-    def eval_deltas(self, block: DeltaBlock
-                    ) -> Tuple[np.ndarray, np.ndarray]:
-        """Evaluate one block of an interval stream given as occupancy
-        deltas; returns int64 ``(faulty, placed)``, each ``(A, rows, T)``.
-
-        The block's masks are built on the device by the evaluator's
-        :class:`IntervalMaskSource` (made at the first block, so one
-        evaluator serves one stream) and go into the grid program without
-        leaving it.
-        A block shorter than ``rows`` runs the same shapes; its pad rows
-        are discarded.
-
-        Spans: as :meth:`eval_block`, with ``sim.jax.put`` copying the
-        deltas and ``churn.device_masks`` around the build's dispatch.
-        """
-        rows = block.rows
-        if self.intervals is None:
-            self.intervals = IntervalMaskSource(self.rows, self.width,
-                                                self.mesh)
-        with obs.span("sim.jax.eval_block", rows=rows, devices=self.ndev):
-            with obs.span("sim.jax.put", rows=rows,
-                          bytes=block.chunks.nbytes):
-                chunks = self.intervals.put(block)
-            with obs.span("churn.device_masks", rows=rows, nodes=self.width):
-                arg = self.intervals.build(block, chunks)
-            obs.count("churn.device_masks_built", rows)
-            return self._grids(arg, rows)
-
-    def _grids(self, arg, rows: int) -> Tuple[np.ndarray, np.ndarray]:
-        """The grid program on a device block, waited for and fetched."""
-        with warnings.catch_warnings():
-            # bool/int32 donation can't alias int32 outputs; the donation
-            # still releases the chunk buffer eagerly, which is the point
-            warnings.filterwarnings("ignore", message=".*onat.*buffer.*")
-            out = self.fn(arg)                     # (padded, A, 2, T)
-        jax.block_until_ready(out)
-        with obs.span("sim.jax.fetch", rows=rows):
-            out = np.asarray(out)
-            return (out[:rows, :, 0].transpose(1, 0, 2).astype(np.int64),
-                    out[:rows, :, 1].transpose(1, 0, 2).astype(np.int64))
+                arg, nbytes = self.device_source.put(
+                    block, max(self.rows, -(-rows // self.ndev) * self.ndev))
+                sp.set(bytes=nbytes)
+            arg = self.device_source.build(block, arg)
+            with warnings.catch_warnings():
+                # bool/int32 donation can't alias int32 outputs; the
+                # donation still releases the block buffer eagerly, which
+                # is the point
+                warnings.filterwarnings("ignore", message=".*onat.*buffer.*")
+                out = self.fn(arg)                 # (padded, A, 2, T)
+            jax.block_until_ready(out)
+            with obs.span("sim.jax.fetch", rows=rows):
+                out = np.asarray(out)
+                return (out[:rows, :, 0].transpose(1, 0, 2).astype(np.int64),
+                        out[:rows, :, 1].transpose(1, 0, 2).astype(np.int64))
 
 
-def sweep_grids(models: Sequence[HBDModel], tps: Sequence[int], *,
-                masks: Optional[np.ndarray] = None,
-                gen: Optional[MaskGen] = None,
-                chunk_snapshots: int = 1024) -> Tuple[np.ndarray, np.ndarray,
-                                                      np.ndarray]:
-    """Evaluate the grid on device; returns int64 (total, faulty, placed).
-
-    Exactly one of ``masks`` (host snapshot matrix) and ``gen``
-    (counter masks drawn on the device, block by block) must be provided.
-    """
-    if (masks is None) == (gen is None):
-        raise ValueError("provide exactly one of masks= and gen=")
-    if masks is not None:
-        masks = np.asarray(masks, dtype=bool)
-        snaps, width = masks.shape
-    else:
-        snaps, width = gen.samples, gen.num_nodes
-
-    a_count, t_count = len(models), len(tps)
-    total = np.zeros((a_count, t_count), dtype=np.int64)
-    faulty = np.zeros((a_count, snaps, t_count), dtype=np.int64)
-    placed = np.zeros((a_count, snaps, t_count), dtype=np.int64)
-    if snaps == 0:  # NumPy engine's zero-snapshot grid keeps totals at zero
-        return total, faulty, placed
-
-    chunk = max(1, chunk_snapshots)
-    with obs.span("sim.jax.setup", rows=snaps):
-        ev = GridEvaluator(models, tps, width, gen=gen,
-                           rows=min(chunk, snaps))
-        total[:] = ev.totals()
-    for lo in range(0, snaps, ev.rows):
-        hi = min(lo + ev.rows, snaps)
-        block = (masks[lo:hi] if masks is not None
-                 else np.arange(lo, hi, dtype=np.int64))
-        f, p = ev.eval_block(block)
-        faulty[:, lo:hi] = f
-        placed[:, lo:hi] = p
-    return total, faulty, placed
-
-
-def counter_masks_device(gen: MaskGen, start: int = 0) -> np.ndarray:
-    """Rows ``start..start+samples-1`` of the counter stream, drawn on the
-    device by the sweep's own draw program and returned as a host bool
-    matrix (for tests and tools).  Bit-identical to
-    ``repro.core.prng.counter_fault_masks(..., start=start)``."""
-    if not HAVE_JAX:
-        raise RuntimeError(f"jax unavailable ({_IMPORT_ERROR!r})")
-    if gen.samples == 0 or gen.num_nodes == 0:
-        return np.zeros((gen.samples, gen.num_nodes), bool)
-    keys = cprng.threefry_fold_in_batch(
-        cprng.threefry_seed(gen.seed),
-        np.arange(start, start + gen.samples, dtype=np.int64))
-    draw = _draw_fn(gen.num_nodes, None)
-    return np.asarray(draw(jnp.asarray(keys),
-                           *_threshold_args(gen.fault_ratio)))
-
-
-def interval_masks_device(blocks: Iterable[DeltaBlock], width: int,
-                          rows: int) -> Iterator[np.ndarray]:
-    """The masks of an interval stream's delta ``blocks`` (of up to
-    ``rows`` rows each), built on the device by the churn stream's own
-    program over the engine's devices and returned block by block as host
-    bool matrices (for tests and tools).  Bit-identical to
-    ``repro.core.trace.FaultTrace.fault_mask_blocks``."""
+def device_masks(source, rows: int) -> Iterator[np.ndarray]:
+    """Every block of ``source`` (``repro.sim.sources``, blocks of up to
+    ``rows`` rows) as its device half builds it over the engine's devices,
+    returned as host bool matrices (for tests and tools): bit-identical to
+    the source's ``host_masks``."""
     if not HAVE_JAX:
         raise RuntimeError(f"jax unavailable ({_IMPORT_ERROR!r})")
     mesh = snapshot_mesh(_SNAP_AXIS)
     ndev = 1 if mesh is None else mesh.devices.size
-    source = IntervalMaskSource(-(-max(1, rows) // ndev) * ndev, width,
-                                mesh)
-    for block in blocks:
-        out = source.build(block, source.put(block))
-        yield np.asarray(out)[:block.rows]
+    padded = -(-max(1, rows) // ndev) * ndev
+    half = None
+    for block in source.blocks(rows):
+        if half is None:
+            half = source.device(padded, source.width, mesh)
+        arg, _ = half.put(block, padded)
+        yield np.asarray(half.build(block, arg))[:len(block)]
 
 
 def num_devices() -> int:
@@ -660,7 +594,7 @@ def num_devices() -> int:
 
 
 __all__ = [
-    "HAVE_JAX", "GridEvaluator", "MaskGen", "available_for", "require",
-    "IntervalMaskSource", "sweep_grids", "counter_masks_device",
-    "interval_masks_device", "num_devices",
+    "HAVE_JAX", "CounterDraw", "GridEvaluator", "HostMaskCopy",
+    "IntervalMaskSource", "available_for", "device_masks", "num_devices",
+    "require",
 ]

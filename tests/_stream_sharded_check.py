@@ -12,9 +12,9 @@ import numpy as np  # noqa: E402
 import jax  # noqa: E402
 
 from repro.churn.monte_carlo import ChurnSpec, monte_carlo_replay  # noqa: E402
-from repro.core.trace import interval_delta_blocks  # noqa: E402
-from repro.sim.jax_backend import interval_masks_device  # noqa: E402
+from repro.sim.jax_backend import device_masks  # noqa: E402
 from repro.sim.engine import evaluate_mask_stream, evaluate_masks, run_sweep  # noqa: E402
+from repro.sim.sources import IntervalDeltas, MaskGen  # noqa: E402
 from repro.sim.scenario import CounterIIDSnapshots, ScenarioSpec  # noqa: E402
 
 ARCHES = ("infinitehbd-k3", "nvl-72")
@@ -65,10 +65,14 @@ def main():
     pieces = [(tr, tr.interval_edges()) for tr in trs]
     want = np.concatenate([tr.fault_masks(ts) for tr, ts in pieces])
     for block, capacity in ((7, 3), (64, 4096)):
-        got = np.concatenate(list(interval_masks_device(
-            interval_delta_blocks(pieces, block, capacity),
-            trs[0].num_nodes, block)))
+        got = np.concatenate(list(device_masks(
+            IntervalDeltas(pieces, trs[0].num_nodes, capacity), block)))
         assert np.array_equal(got, want), (block, capacity)
+
+    # counter masks drawn on the devices, 13-row blocks padded to 16
+    got = np.concatenate(list(device_masks(
+        MaskGen(93, spec.num_nodes, 0.09, seed=4), 13)))
+    assert np.array_equal(got, masks)
 
     print("OK stream_sharded")
 

@@ -125,12 +125,13 @@ def test_threefry2x32_jnp_matches_numpy_on_array_keys(seed):
 @pytest.mark.parametrize("num_nodes", [97, 64])
 @pytest.mark.parametrize("start", [0, 1_000_003])
 def test_counter_masks_jax_matches_numpy_mirror(num_nodes, start):
-    from repro.sim.jax_backend import MaskGen, counter_masks_device
+    from repro.sim.jax_backend import device_masks
+    from repro.sim.sources import MaskGen
     for ratio, seed in ((0.07, 0), (0.5, 11), (0.0, 3), (1.0, 5),
                         (0.07, 5_200_000_000)):
         gen = MaskGen(samples=13, num_nodes=num_nodes, fault_ratio=ratio,
-                      seed=seed)
-        dev = counter_masks_device(gen, start=start)
+                      seed=seed, start=start)
+        [dev] = device_masks(gen, 13)
         host = counter_fault_masks(num_nodes, ratio, 13, seed, start=start)
         assert np.array_equal(dev, host), (ratio, seed)
 

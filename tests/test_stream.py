@@ -1,9 +1,10 @@
 """Streaming evaluation paths: bit-for-bit equality with the batched ones.
 
-The streaming engine (``evaluate_mask_stream``), the streamed counter-mask
-``run_sweep`` path, and ``monte_carlo_replay(engine="streamed")`` must all
-reproduce the batched grids exactly -- for any chunking, including chunk
-sizes of 1 and larger than the whole stream.
+The engine's one loop over a mask source (``evaluate_source``) behind
+``evaluate_mask_stream``, the streamed counter-mask ``run_sweep`` path and
+``monte_carlo_replay(engine="streamed")`` must all reproduce the batched
+grids exactly -- for any chunking, including chunk sizes of 1 and larger
+than the whole stream.
 """
 
 import os
@@ -17,8 +18,9 @@ import pytest
 from repro.churn.monte_carlo import ChurnSpec, monte_carlo_replay
 from repro.core.prng import counter_fault_masks
 from repro.sim.engine import (evaluate_mask_stream, evaluate_masks,
-                              run_sweep)
+                              evaluate_source, run_sweep)
 from repro.sim.scenario import CounterIIDSnapshots, ScenarioSpec
+from repro.sim.sources import HostChunks, IntervalDeltas, MaskGen
 
 ARCHES = ("infinitehbd-k3", "nvl-72")
 
@@ -162,10 +164,32 @@ def test_stream_jax_backend_matches_numpy():
         assert np.array_equal(g, r)
 
 
-def test_stream_jax_backend_runs_one_compiled_shape():
+def _stream(kind, seed, length):
+    """A mask source of ``kind`` over 144 nodes and the masks it streams:
+    ``length`` counter snapshots in ragged host chunks or drawn on the
+    device, or two realizations of ``length`` hours of the churn trace
+    as interval deltas, four to a chunk (most blocks take several
+    chunks)."""
+    if kind == "interval":
+        spec = ChurnSpec(trace_nodes=72, horizon_h=float(length), seed=seed)
+        pieces = [(tr, tr.interval_edges())
+                  for tr in (spec.trace(0), spec.trace(1))]
+        masks = np.concatenate([tr.fault_masks(ts) for tr, ts in pieces])
+        return IntervalDeltas(pieces, 144, capacity=4), masks
+    masks = counter_fault_masks(144, 0.07, length, seed)
+    if kind == "counter":
+        return MaskGen(length, 144, 0.07, seed), masks
+    third = length // 3
+    return HostChunks(_split(masks, [third, 1, length - third - 1])), masks
+
+
+@pytest.mark.parametrize("kind", ["host", "counter", "interval"])
+def test_stream_jax_backend_runs_one_compiled_shape(kind):
     """Streams whose last blocks differ in length pad them up to the block:
-    after one stream has compiled the block, no other stream of that block
-    size compiles anything, whatever its length."""
+    after one stream of a source has compiled the block, no other stream
+    of that source and block size compiles anything, whatever its length
+    -- host chunks straddling the blocks, counter masks drawn on the
+    device and interval masks built on the device alike."""
     pytest.importorskip("jax")
     from jax import monitoring
     events = ("/jax/core/compile/backend_compile_duration",
@@ -176,25 +200,26 @@ def test_stream_jax_backend_runs_one_compiled_shape():
         if event in events:
             compiles.append(event)
 
-    spec = _spec(61, num_nodes=144, seed=5)
-    models = spec.models()
-    masks = spec.snapshots.masks(spec.num_nodes)
-    evaluate_mask_stream(models, spec.tp_sizes, [masks[:16]], 16,
-                         chunk_snapshots=8, backend="jax")
+    models = _spec(1, num_nodes=144).models()
+
+    def run(seed, length):
+        source, masks = _stream(kind, seed, length)
+        ref = evaluate_masks(models, (16, 64), masks, backend="numpy")
+        got = evaluate_source(models, (16, 64), source, masks.shape[0],
+                              chunk_snapshots=8, backend="jax")
+        assert got[3] == "jax"
+        for g, r in zip(got[:3], ref[:3]):
+            assert np.array_equal(g, r)
+        return masks.shape[0]
+
+    assert run(1, 40) > 8
     monitoring.register_event_duration_secs_listener(listen)
     try:
-        # tails of 5 and 3 rows, source chunks straddling the blocks
-        for total, sizes in ((61, [13, 30, 18]), (43, [10, 1, 32])):
-            ref = evaluate_masks(models, spec.tp_sizes, masks[:total],
-                                 backend="numpy")
-            got = evaluate_mask_stream(models, spec.tp_sizes,
-                                       _split(masks[:total], sizes), total,
-                                       chunk_snapshots=8, backend="jax")
-            for g, r in zip(got[:3], ref[:3]):
-                assert np.array_equal(g, r)
+        tails = [run(seed, length) % 8 for seed, length in ((5, 61), (7, 43))]
     finally:
         monitoring.unregister_event_duration_listener(listen)
     assert compiles == []
+    assert all(tails) and len(set(tails)) == 2
 
 
 # the same three realizations as above, their masks built on the device
@@ -234,59 +259,14 @@ def test_monte_carlo_streamed_jax_empty_and_short():
 
 def test_delta_stream_length_mismatch_raises():
     pytest.importorskip("jax")
-    from repro.core.trace import interval_delta_blocks
-    from repro.sim.engine import evaluate_delta_stream
     spec = ChurnSpec(trace_nodes=20, horizon_h=48.0, tp_sizes=(16,),
                      architectures=ARCHES, seed=2)
     tr = spec.trace(0)
     edges = tr.interval_edges()
-    blocks = interval_delta_blocks([(tr, edges)], 8)
     with pytest.raises(ValueError, match=f"yielded {len(edges)}"):
-        evaluate_delta_stream(spec.models(), spec.tp_sizes, tr.num_nodes,
-                              blocks, len(edges) + 1, chunk_snapshots=8)
-
-
-def test_delta_stream_runs_one_compiled_shape():
-    """After one churn stream has compiled its block, streams of other
-    lengths and realizations (short last blocks, blocks straddling
-    realizations, chunks of deltas beyond one call) compile nothing."""
-    pytest.importorskip("jax")
-    from jax import monitoring
-    from repro.core import trace as ctrace
-    events = ("/jax/core/compile/backend_compile_duration",
-              "/jax/compilation_cache/cache_retrieval_time_sec")
-    compiles = []
-
-    def listen(event, duration, **_):
-        if event in events:
-            compiles.append(event)
-
-    def spec(seed, days):
-        return ChurnSpec(trace_nodes=50, horizon_h=24.0 * days,
-                         tp_sizes=(16, 32), architectures=ARCHES, seed=seed)
-
-    warm = monte_carlo_replay(spec(1, 4), 1, engine="streamed",
-                              backend="jax", chunk_snapshots=16)
-    assert warm.timelines[0].num_intervals > 16
-    monitoring.register_event_duration_secs_listener(listen)
-    try:
-        for seed, days, count in ((5, 9, 2), (6, 4, 3)):
-            ref = monte_carlo_replay(spec(seed, days), count,
-                                     engine="batched", backend="numpy")
-            got = monte_carlo_replay(spec(seed, days), count,
-                                     engine="streamed", backend="jax",
-                                     chunk_snapshots=16)
-            for tg, tr in zip(got.timelines, ref.timelines):
-                assert np.array_equal(tg.faulty_gpus, tr.faulty_gpus)
-                assert np.array_equal(tg.placed_gpus, tr.placed_gpus)
-        # 4 deltas per call: most blocks take several calls
-        blocks = ctrace.interval_delta_blocks(
-            [(spec(7, 3).trace(0), spec(7, 3).trace(0).interval_edges())],
-            16, capacity=4)
-        assert max(len(b.starts) for b in blocks) > 1
-    finally:
-        monitoring.unregister_event_duration_listener(listen)
-    assert compiles == []
+        evaluate_source(spec.models(), spec.tp_sizes,
+                        IntervalDeltas([(tr, edges)], tr.num_nodes),
+                        len(edges) + 1, chunk_snapshots=8, backend="jax")
 
 
 def _end_inclusive(monkeypatch):

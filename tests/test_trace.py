@@ -13,6 +13,7 @@ from repro.core.trace import (BAYES_SPLIT_P, FAULT_RATIO_4GPU,
                               MEAN_FAULT_RATIO_8GPU, FaultEvent, FaultTrace,
                               generate_trace, interval_delta_blocks,
                               to_4gpu_trace)
+from repro.sim.sources import IntervalDeltas
 
 
 def test_bayes_split_constant():
@@ -191,7 +192,8 @@ def test_interval_delta_blocks_build_the_host_masks(block, which):
     for b in blocks:
         out, carry = b.host_masks(width, carry)
         host.append(out)
-    device = list(jax_backend.interval_masks_device(blocks, width, block))
+    device = list(jax_backend.device_masks(
+        IntervalDeltas(pieces, width, capacity=8), block))
     assert np.array_equal(np.concatenate(host), want)
     assert np.array_equal(np.concatenate(device), want)
 
