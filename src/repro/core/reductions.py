@@ -5,7 +5,13 @@ One implementation of the mean/percentile/threshold reductions that both
 ``repro.core.fault_sim`` (per-model grids) apply -- previously duplicated in
 both modules and pinned bit-for-bit to the scalar paths by
 ``tests/test_sim_engine.py``.  Keep the float conversions exactly as they
-are: reordering them changes low bits and breaks the pinning.
+are: reordering them changes low bits and breaks the pinning.  The same
+holds for the layout: :func:`waste_stats` and :func:`percentile_capacity`
+take the snapshots on the first axis and reduce every trailing series in
+one call, with the snapshot axis moved last and made contiguous -- a mean
+along a contiguous last axis is NumPy's pairwise summation, as the 1-D
+call's is, where a reduction over a non-contiguous axis sums naively and
+changes low bits.  Percentiles keep the ``linear`` method.
 
 Also home to the sparse *segment* reductions of the batched DCN placement
 hot path (:func:`run_segments`, :func:`segment_carve_counts`): the K-hop
@@ -21,17 +27,41 @@ from typing import Tuple
 import numpy as np
 
 
-def waste_stats(series: np.ndarray) -> Tuple[float, float, float]:
-    """(mean, P50, P99) of a waste-ratio series (Fig. 13/14 reductions)."""
-    series = np.asarray(series)
-    return (float(series.mean()), float(np.percentile(series, 50)),
-            float(np.percentile(series, 99)))
+def _snapshots_last(series, dtype=None) -> np.ndarray:
+    """``series`` (snapshots first) with the snapshot axis moved last and
+    made contiguous; no copy where it already lies so."""
+    return np.ascontiguousarray(np.moveaxis(np.asarray(series), 0, -1),
+                                dtype=dtype)
 
 
-def percentile_capacity(placed: np.ndarray, percentile: float = 5.0) -> float:
+def _percentiles(x: np.ndarray, q):
+    """``linear`` percentiles ``q`` along ``x``'s last axis.  Sorting a
+    copy first lets the percentile's partition run on sorted rows, which is
+    faster and reads the same values."""
+    return np.percentile(np.sort(x, axis=-1), q, axis=-1,
+                         overwrite_input=True)
+
+
+def _scalar(value):
+    """A Python float for a 1-D series' 0-d result, the array otherwise."""
+    return float(value) if np.ndim(value) == 0 else value
+
+
+def waste_stats(series: np.ndarray) -> Tuple:
+    """(mean, P50, P99) over snapshots of a waste-ratio series (Fig. 13/14
+    reductions).  The first axis is snapshots; each result has the shape of
+    the trailing axes, a Python float for a 1-D series."""
+    x = _snapshots_last(series)
+    p50, p99 = _percentiles(x, [50, 99])
+    return tuple(_scalar(v) for v in (x.mean(axis=-1), p50, p99))
+
+
+def percentile_capacity(placed: np.ndarray, percentile: float = 5.0):
     """Placeable-GPU percentile over snapshots -- P5 is the job scale a long
-    run could hold through ~95% of the trace (Fig. 15)."""
-    return float(np.percentile(np.asarray(placed).astype(float), percentile))
+    run could hold through ~95% of the trace (Fig. 15).  The first axis is
+    snapshots; the result has the shape of the trailing axes, a Python
+    float for a 1-D series."""
+    return _scalar(_percentiles(_snapshots_last(placed, float), percentile))
 
 
 def waiting_share(placed: np.ndarray, job_gpus: int) -> float:

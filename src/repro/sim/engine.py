@@ -109,11 +109,14 @@ class SweepResult:
 
     @property
     def waste_ratio(self) -> np.ndarray:
-        total = np.broadcast_to(self.total_gpus[:, None, :],
-                                self.placed_gpus.shape)
-        return np.divide(self.healthy_gpus - self.placed_gpus, total,
-                         out=np.zeros(self.placed_gpus.shape),
-                         where=total != 0)
+        """``(healthy - placed) / total`` as ``(A, S, T)``, 0 where an
+        architecture has no GPUs.  Laid out snapshot axis last, so each
+        (architecture, TP) series is contiguous for the table reductions."""
+        wasted = np.moveaxis(self.healthy_gpus - self.placed_gpus, 1, -1)
+        total = np.broadcast_to(self.total_gpus[:, :, None], wasted.shape)
+        waste = np.divide(wasted, total, out=np.zeros(wasted.shape),
+                          where=total != 0)
+        return np.moveaxis(waste, -1, 1)
 
     def index(self, name: str) -> int:
         return self.names.index(name)

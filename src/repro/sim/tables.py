@@ -14,6 +14,8 @@ from __future__ import annotations
 import io
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from .. import obs
 from ..core.reductions import (percentile_capacity, waiting_share,
                                waste_stats)
@@ -21,29 +23,35 @@ from .engine import SweepResult
 
 
 def waste_table(result: SweepResult) -> List[Dict]:
-    """Per (architecture, TP): mean/P50/P99 waste ratio over snapshots."""
-    with obs.span("sim.tables.waste_table", rows=result.num_snapshots):
-        waste = result.waste_ratio
+    """Per (architecture, TP): mean/P50/P99 waste ratio over snapshots,
+    every series reduced in one call with the snapshot axis first."""
+    with obs.span("sim.tables.waste_table", rows=result.num_snapshots,
+                  cells=len(result.names) * len(result.tp_sizes)):
+        mean, p50, p99 = waste_stats(np.moveaxis(result.waste_ratio, 1, 0))
         rows = []
         for ai, name in enumerate(result.names):
             for ti, tp in enumerate(result.tp_sizes):
-                mean, p50, p99 = waste_stats(waste[ai, :, ti])
                 rows.append({
                     "architecture": name, "tp_size": int(tp),
-                    "mean_waste": mean, "p50_waste": p50, "p99_waste": p99,
+                    "mean_waste": float(mean[ai, ti]),
+                    "p50_waste": float(p50[ai, ti]),
+                    "p99_waste": float(p99[ai, ti]),
                 })
     return rows
 
 
 def max_job_table(result: SweepResult, percentile: float = 5.0) -> List[Dict]:
     """Per (architecture, TP): P5 of placeable GPUs -- the job scale a long
-    run could hold through ~95% of the trace (Fig. 15)."""
-    with obs.span("sim.tables.max_job_table", rows=result.num_snapshots):
+    run could hold through ~95% of the trace (Fig. 15) -- every series
+    reduced in one call with the snapshot axis first."""
+    with obs.span("sim.tables.max_job_table", rows=result.num_snapshots,
+                  cells=len(result.names) * len(result.tp_sizes)):
+        capacity = percentile_capacity(
+            np.moveaxis(result.placed_gpus, 1, 0), percentile)
         rows = []
         for ai, name in enumerate(result.names):
             for ti, tp in enumerate(result.tp_sizes):
-                gpus = percentile_capacity(result.placed_gpus[ai, :, ti],
-                                           percentile)
+                gpus = float(capacity[ai, ti])
                 total = int(result.total_gpus[ai, ti])
                 rows.append({
                     "architecture": name, "tp_size": int(tp),

@@ -288,7 +288,8 @@ def test_sweep_host_layer_spans(tel):
         assert draw.start_ns + draw.dur_ns <= fetch.start_ns
     for name in ("sim.tables.waste_table", "sim.tables.max_job_table"):
         [table] = _named(tel, name)
-        assert table.attrs == {"rows": 64}
+        assert table.attrs == {"rows": 64,
+                               "cells": len(ARCHES) * len(result.tp_sizes)}
     # nothing left of the per-block rate instruments
     assert "sim.jax.snaps_per_sec" not in obs.summary()["gauges"]
     assert all("snaps_per_sec" not in b.attrs for b in blocks)

@@ -156,6 +156,72 @@ def test_sweep_tables_shapes():
     assert len(csv.splitlines()) == 5
 
 
+# ------------------------------- vectorised table reductions == per series
+
+def _per_series_tables(names, tps, total, faulty, placed, percentile):
+    """The per-(architecture, TP) loop the one-call reductions replaced:
+    1-D float64 slices, one mean and one percentile call per statistic."""
+    tot = np.broadcast_to(total[:, None, :], placed.shape)
+    waste = np.divide(tot - faulty - placed, tot,
+                      out=np.zeros(placed.shape), where=tot != 0)
+    waste_rows, job_rows = [], []
+    for ai, name in enumerate(names):
+        for ti, tp in enumerate(tps):
+            series = waste[ai, :, ti]
+            waste_rows.append({
+                "architecture": name, "tp_size": int(tp),
+                "mean_waste": float(series.mean()),
+                "p50_waste": float(np.percentile(series, 50)),
+                "p99_waste": float(np.percentile(series, 99))})
+            gpus = float(np.percentile(placed[ai, :, ti].astype(float),
+                                       percentile))
+            t = int(total[ai, ti])
+            job_rows.append({
+                "architecture": name, "tp_size": int(tp),
+                "max_job_gpus": gpus,
+                "fraction": gpus / t if t else 0.0})
+    return waste, waste_rows, job_rows
+
+
+@pytest.mark.parametrize("snapshots", [1, 2, 7, 129, 256, 1000, 8192])
+def test_vectorised_table_reductions_match_per_series_loop(snapshots):
+    from repro.core.reductions import percentile_capacity, waste_stats
+    from repro.sim import max_job_table, waste_table
+    from repro.sim.engine import SweepResult
+    rng = np.random.default_rng(snapshots)
+    names = ["a0", "no-gpus", "ties", "constant", "a4"]
+    tps = np.array([16, 32, 64])
+    A, S, T = len(names), snapshots, len(tps)
+    total = rng.integers(1024, 131072, (A, T))
+    total[1] = 0                                 # an architecture with no GPUs
+    faulty = np.minimum(rng.integers(0, 4096, (A, S, T)), total[:, None, :])
+    placed = (rng.random((A, S, T))
+              * (total[:, None, :] - faulty)).astype(np.int64)
+    placed[2] = np.minimum(rng.choice([0, 512, 1024], (S, T)),
+                           total[2] - faulty[2])  # heavily tied values
+    faulty[3], placed[3] = faulty[3, :1], placed[3, :1]  # all-equal series
+    result = SweepResult(spec=None, names=names, tp_sizes=tps,
+                         total_gpus=total, faulty_gpus=faulty,
+                         placed_gpus=placed)
+    for percentile in (5.0, 50.0):
+        waste, waste_rows, job_rows = _per_series_tables(
+            names, tps, total, faulty, placed, percentile)
+        assert np.array_equal(result.waste_ratio, waste)
+        assert waste_table(result) == waste_rows
+        assert max_job_table(result, percentile) == job_rows
+    # a 1-D series is the S-only case: Python floats, the same values
+    for ai in range(A):
+        for ti in range(T):
+            stats = waste_stats(waste[ai, :, ti])
+            row = waste_rows[ai * T + ti]
+            assert all(type(v) is float for v in stats)
+            assert stats == (row["mean_waste"], row["p50_waste"],
+                             row["p99_waste"])
+            gpus = percentile_capacity(placed[ai, :, ti], 50.0)
+            assert type(gpus) is float
+            assert gpus == job_rows[ai * T + ti]["max_job_gpus"]
+
+
 # ------------------------------------------- incremental == full orchestration
 
 @pytest.mark.parametrize("seed", range(6))
